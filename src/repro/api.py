@@ -64,9 +64,11 @@ dict keys keep working as deprecated aliases:
 old dict access                         Telemetry field
 ======================================  ===================================
 ``t["backend"] / ["calls"] /``          same-named top-level fields
-``["queries"] / ["wave_calls"]``
+``["queries"] / ["wave_calls"] /``      (``host_syncs``: blocking device→
+``["host_syncs"]``                      host reads and waits)
 ``t["plan_cache"]["hits" | ...]``       ``t.plan_cache.hits`` ...
-``t["latency_s"]["total" | ...]``       ``t.latency.total`` ...
+``t["latency_s"]["total"]``             ``t.latency.total`` (seconds in
+                                        the plans, waits included)
 ``t["paths"]["scan_eapca" | ...]``      ``t.paths.scan_eapca`` ...
 ``t["pruning"]["eapca_mean" | ...]``    ``t.pruning.eapca_mean`` ...
 ``t["ooc"]["rows_streamed" | ...]``     ``t.ooc.rows_streamed`` ... (the
@@ -74,11 +76,15 @@ old dict access                         Telemetry field
                                         for in-memory backends; it now also
                                         carries ``bytes_streamed`` and the
                                         ``codec_refine_rows`` /
-                                        ``codec_fallbacks`` counters)
+                                        ``codec_fallbacks`` counters, and
+                                        the stream's ``host_syncs``)
 ``t["dist"]["rows_streamed" | ...]``    ``t.dist.rows_streamed`` ...
                                         (per-shard lists; ``None`` — key
                                         absent — except under ``dist-ooc``)
-``t["serving"]`` (KnnServeEngine)       ``t.serving``
+``t["serving"]["waves" | ...]``         ``t.serving.waves`` ... (filled
+                                        by KnnServeEngine; ``queue_wait_s``
+                                        / ``dequeued``: submit to wave
+                                        start, summed over the dequeued)
 ======================================  ===================================
 
 Deprecated entry points (kept working; each docstring names its successor):
@@ -108,7 +114,8 @@ from repro.core.engine import (  # noqa: F401
     EngineConfig, LatencyTelemetry, LocalBackend, OocTelemetry,
     OutOfCoreLocalBackend, OutOfCoreScanBackend, PathsTelemetry,
     PlanCacheTelemetry, PruningTelemetry, QueryEngine, ScanBackend,
-    SearchBackend, ShardedBackend, Telemetry, backend_names, dense_scan_knn,
+    SearchBackend, ServingTelemetry, ShardedBackend, Telemetry,
+    backend_names, dense_scan_knn,
     kernel_scan_knn, make_backend, make_disk_backend, resolve_backend_name,
 )
 from repro.kernels.compat import KERNEL_MODES, resolve_kernel_mode  # noqa: F401

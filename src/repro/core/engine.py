@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.core import summaries as S
 from repro.core.index import HerculesIndex, IndexConfig
+from repro.core.probes import HostSyncs, span
 from repro.core.search import (INF, KnnResult, SearchConfig, _merge_topk,
                                exact_knn, pscan_knn, validate_runtime_config,
                                wave_knn)
@@ -173,6 +174,8 @@ class LocalBackend(BackendBase):
 
     def __init__(self, index: HerculesIndex):
         self.index = index
+        # read from the device once here, so telemetry() never waits on it
+        self._stats = index.stats()
 
     @property
     def series_len(self) -> int:
@@ -207,7 +210,7 @@ class LocalBackend(BackendBase):
             _wave_leaf_lbs(jnp.asarray(queries), self.index.layout))
 
     def stats(self) -> dict:
-        return self.index.stats()
+        return dict(self._stats)
 
     def describe(self) -> dict:
         d = super().describe()
@@ -563,6 +566,7 @@ class _OutOfCoreBase(BackendBase):
         self.memory_budget_mb = float(memory_budget_mb)
         self._config = config or saved.config.search
         self._perm = jnp.asarray(saved.small["perm"])
+        self._syncs = HostSyncs()
         self._t = {"calls": 0, "blocks": 0, "rows_streamed": 0,
                    "bytes_streamed": 0, "sax_rows_read": 0,
                    "read_seconds": 0.0, "read_wait_seconds": 0.0,
@@ -662,6 +666,7 @@ class _OutOfCoreBase(BackendBase):
                 "series_len": self.saved.series_len,
                 "memory_budget_mb": self.memory_budget_mb,
                 "codec": getattr(self.saved, "codec", "raw"),
+                "host_syncs": self._syncs.count,
                 **self._t}
 
     def _codec_finalize(self, q, cfg: SearchConfig, ub_d, ub_p, lb_d, lb_p,
@@ -679,13 +684,13 @@ class _OutOfCoreBase(BackendBase):
         # every row not carried in the LB pool had LB >= the pool's largest
         # kept LB; if that is >= theta (>= the true kth distance), dropped
         # and pruned rows can at most tie the kth answer
-        certified = np.asarray(lb_d[:, -1] >= theta)
+        certified = self._syncs.read(lb_d[:, -1] >= theta)
         if valid_rows is not None:
             certified = certified[:valid_rows]
         bad = int(certified.size - int(certified.sum()))
         if bad:
             return None, None, bad
-        cand_p = np.asarray(lb_p)
+        cand_p = self._syncs.read(lb_p)
         safe = np.clip(cand_p, 0, max(self.saved.n_pad - 1, 0))
         # np.take = copy-guaranteed gather of the candidate rows (never a
         # view of the mapped file, so the device transfer cannot alias it)
@@ -926,8 +931,34 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
         dead = jnp.asarray(self._leaf_count) <= 0
         return jnp.where(dead[None, :], INF, lbs)
 
-    def _stream_knn(self, q: jax.Array, cfg: SearchConfig) -> KnnResult:
+    def _seed_candidates(self, q: jax.Array, cfg: SearchConfig):
+        """Phase 1's inputs (Alg. 11): the (Q, L) LB_EAPCA of every query
+        to every leaf, and on the host each query's home leaf rank and its
+        ``l_max`` best leaf ranks by that bound."""
         from repro.core.tree import route_to_leaf
+
+        lbs = self._leaf_lbs(q)                              # (Q, L)
+        home_nodes = route_to_leaf(self.saved.tree, q, self.saved.max_depth)
+        home_ranks = self._syncs.read(self._leaf_rank)[
+            self._syncs.read(home_nodes)]
+        l_max = min(cfg.l_max, self.saved.num_leaves)
+        _, best = jax.lax.top_k(-lbs, l_max)                 # (Q, l_max)
+        return lbs, home_ranks, self._syncs.read(best)
+
+    def _needed_leaves(self, lbs: jax.Array, bsf: jax.Array, slack,
+                       seeded: list[int]):
+        """Phase 2 (Alg. 12): the leaves some query cannot prune against
+        its ``bsf``, less the ``seeded`` ones already read, and each query's
+        leaf-level pruning ratio."""
+        cand = lbs * slack < bsf[:, None]                    # (Q, L)
+        needed = np.array(self._syncs.read(jnp.any(cand, axis=0)))
+        needed[seeded] = False
+        n_alive = max(int((np.asarray(self._leaf_count) > 0).sum()), 1)
+        eapca_pr = 1.0 - self._syncs.read(
+            jnp.sum(cand, axis=1)).astype(np.float32) / n_alive
+        return needed, eapca_pr
+
+    def _stream_knn(self, q: jax.Array, cfg: SearchConfig) -> KnnResult:
         from repro.data.pipeline import make_chunk_reader
 
         k = cfg.k
@@ -954,8 +985,9 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                 lrd_reader.submit(start, cnt, pad_to)
             for start, cnt, _ in extents:
                 rows = lrd_reader.stage(lrd_reader.get())
-                d, p = _ooc_refine_block(rows, jnp.int32(start),
-                                         jnp.int32(cnt), q, d, p, k=k)
+                with span("repro.ooc.refine", rows=cnt):
+                    d, p = _ooc_refine_block(rows, jnp.int32(start),
+                                             jnp.int32(cnt), q, d, p, k=k)
                 self._count(cnt)
             return d, p
 
@@ -964,35 +996,28 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
             # its l_max best leaves by LB_EAPCA — same visit set as the
             # in-memory pipeline, so the bound entering phase 2 is comparably
             # tight.
-            lbs = self._leaf_lbs(q)                          # (Q, L)
-            home_nodes = route_to_leaf(self.saved.tree, q,
-                                       self.saved.max_depth)
-            home_ranks = np.asarray(self._leaf_rank)[np.asarray(home_nodes)]
-            l_max = min(cfg.l_max, self.saved.num_leaves)
-            _, best = jax.lax.top_k(-lbs, l_max)             # (Q, l_max)
-            seeded = sorted(set(int(r) for r in home_ranks if r >= 0)
-                            | set(int(r) for r in np.asarray(best).ravel()))
-            seeds = [(int(self._leaf_start[r]), int(self._leaf_count[r]),
-                      max_leaf) for r in seeded
-                     if int(self._leaf_count[r]) > 0]
-            seed_rows = sum(cnt for _, cnt, _ in seeds)
-            d, p = refine_all(d, p, seeds)
+            with span("repro.ooc.seed"):
+                lbs, home_ranks, best = self._seed_candidates(q, cfg)
+                seeded = sorted(set(int(r) for r in home_ranks if r >= 0)
+                                | set(int(r) for r in best.ravel()))
+                seeds = [(int(self._leaf_start[r]), int(self._leaf_count[r]),
+                          max_leaf) for r in seeded
+                         if int(self._leaf_count[r]) > 0]
+                seed_rows = sum(cnt for _, cnt, _ in seeds)
+                d, p = refine_all(d, p, seeds)
 
             # -- phase 2: leaf-level pruning over resident synopses ----------
-            slack = jnp.float32(1.0 - cfg.lb_slack)
-            bsf = d[:, k - 1]
-            cand = lbs * slack < bsf[:, None]                # (Q, L)
-            needed = np.array(jnp.any(cand, axis=0))
-            needed[seeded] = False
-            n_alive = max(int((np.asarray(self._leaf_count) > 0).sum()), 1)
-            eapca_pr = 1.0 - np.asarray(
-                jnp.sum(cand, axis=1), np.float32) / n_alive
+            with span("repro.ooc.select") as sel:
+                slack = jnp.float32(1.0 - cfg.lb_slack)
+                needed, eapca_pr = self._needed_leaves(lbs, d[:, k - 1],
+                                                       slack, seeded)
+                pieces = self._runs(needed, R)
+                sel.set_metadata(runs=len(pieces))
 
             # -- phase 3: stream the LSD sidecar over non-prunable leaves,
             # keep only series the per-row LB_SAX filter cannot exclude, and
             # fetch those as contiguous LRD runs (the paper's LSDFile pass:
             # m bytes of codes buy skipping n floats of raw series) ---------
-            pieces = self._runs(needed, R)
             use_sax = bool(cfg.use_sax)
             # seeded-leaf rows were read and refined for every query — they
             # count as alive, or sax_pr would overstate pruning (rows the
@@ -1013,28 +1038,32 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                 for start, cnt in pieces:
                     lsd_reader.submit(start, cnt, self._pad_bucket(cnt, R))
                 for start, cnt in pieces:
-                    # codes padded to the same bucketed shapes as the row
-                    # fetches, so the LB kernel compiles O(log) times, not
-                    # once per piece length; pad columns are masked out of
-                    # `live` below
-                    pad_to = self._pad_bucket(cnt, R)
-                    codes = lsd_reader.stage(lsd_reader.get())
-                    ranks = np.zeros((pad_to,), np.int32)
-                    ranks[:cnt] = self._srank[start:start + cnt]
-                    self._t["sax_rows_read"] += cnt
-                    lb_row = jnp.maximum(
-                        kops.lb_sax(q_paa, codes, n, mode=kmode),
-                        lbs[:, ranks])                        # (Q, pad_to)
-                    bsf = d[:, k - 1]
-                    live = ((lb_row * slack < bsf[:, None])
-                            & (jnp.arange(pad_to) < cnt)[None, :])
-                    alive_counts = alive_counts + jnp.sum(live, axis=1,
-                                                          dtype=jnp.int32)
-                    alive = np.asarray(jnp.any(live, axis=0))[:cnt]
+                    with span("repro.ooc.filter", rows=cnt):
+                        # codes padded to the same bucketed shapes as the
+                        # row fetches, so the LB kernel compiles O(log)
+                        # times, not once per piece length; pad columns are
+                        # masked out of `live` below
+                        pad_to = self._pad_bucket(cnt, R)
+                        codes = lsd_reader.stage(lsd_reader.get())
+                        ranks = np.zeros((pad_to,), np.int32)
+                        ranks[:cnt] = self._srank[start:start + cnt]
+                        self._t["sax_rows_read"] += cnt
+                        lb_row = jnp.maximum(
+                            kops.lb_sax(q_paa, codes, n, mode=kmode),
+                            lbs[:, ranks])                    # (Q, pad_to)
+                        bsf = d[:, k - 1]
+                        live = ((lb_row * slack < bsf[:, None])
+                                & (jnp.arange(pad_to) < cnt)[None, :])
+                        alive_counts = alive_counts + jnp.sum(
+                            live, axis=1, dtype=jnp.int32)
+                        alive = self._syncs.read(
+                            jnp.any(live, axis=0))[:cnt]
+                    with span("repro.ooc.select") as sel:
+                        runs = _alive_runs(alive, start)
+                        sel.set_metadata(runs=len(runs))
                     d, p = refine_all(d, p,
                                       [(s0, c0, self._pad_bucket(c0, R))
-                                       for s0, c0 in _alive_runs(alive,
-                                                                 start)])
+                                       for s0, c0 in runs])
             self._t["calls"] += 1
         finally:
             self._reap_reader(lrd_reader)
@@ -1064,7 +1093,6 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
         candidate pool is re-checked against full-precision float32 rows at
         the end: distances bit-identical to the raw stream, with a
         whole-batch fallback to it when the guard cannot certify."""
-        from repro.core.tree import route_to_leaf
         from repro.data.pipeline import make_chunk_reader
 
         k = cfg.k
@@ -1095,43 +1123,37 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                 enc_reader.submit(start, cnt, pad_to)
             for start, cnt, _ in extents:
                 enc = enc_reader.stage(enc_reader.get())
-                ub_d, ub_p, lb_d, lb_p = _codec_bounds_block(
-                    enc, q, jnp.int32(start), jnp.int32(cnt),
-                    ub_d, ub_p, lb_d, lb_p, codec=codec, series_len=n,
-                    k=k, cand=cand, mode=kmode)
+                with span("repro.ooc.refine", rows=cnt):
+                    ub_d, ub_p, lb_d, lb_p = _codec_bounds_block(
+                        enc, q, jnp.int32(start), jnp.int32(cnt),
+                        ub_d, ub_p, lb_d, lb_p, codec=codec, series_len=n,
+                        k=k, cand=cand, mode=kmode)
                 self._count(cnt, row_bytes=W)
             return ub_d, ub_p, lb_d, lb_p
 
         try:
             # -- phase 1: seed the conservative BSF (kth upper bound) from
             # each query's home leaf plus its l_max best leaves ------------
-            lbs = self._leaf_lbs(q)                          # (Q, L)
-            home_nodes = route_to_leaf(self.saved.tree, q,
-                                       self.saved.max_depth)
-            home_ranks = np.asarray(self._leaf_rank)[np.asarray(home_nodes)]
-            l_max = min(cfg.l_max, self.saved.num_leaves)
-            _, best = jax.lax.top_k(-lbs, l_max)             # (Q, l_max)
-            seeded = sorted(set(int(r) for r in home_ranks if r >= 0)
-                            | set(int(r) for r in np.asarray(best).ravel()))
-            seeds = [(int(self._leaf_start[r]), int(self._leaf_count[r]),
-                      max_leaf) for r in seeded
-                     if int(self._leaf_count[r]) > 0]
-            seed_rows = sum(cnt for _, cnt, _ in seeds)
-            ub_d, ub_p, lb_d, lb_p = bounds_all(ub_d, ub_p, lb_d, lb_p,
-                                                seeds)
+            with span("repro.ooc.seed"):
+                lbs, home_ranks, best = self._seed_candidates(q, cfg)
+                seeded = sorted(set(int(r) for r in home_ranks if r >= 0)
+                                | set(int(r) for r in best.ravel()))
+                seeds = [(int(self._leaf_start[r]), int(self._leaf_count[r]),
+                          max_leaf) for r in seeded
+                         if int(self._leaf_count[r]) > 0]
+                seed_rows = sum(cnt for _, cnt, _ in seeds)
+                ub_d, ub_p, lb_d, lb_p = bounds_all(ub_d, ub_p, lb_d, lb_p,
+                                                    seeds)
 
             # -- phase 2: leaf-level pruning against the kth upper bound ---
-            slack = jnp.float32(1.0 - cfg.lb_slack)
-            bsf = ub_d[:, k - 1]
-            cand_l = lbs * slack < bsf[:, None]              # (Q, L)
-            needed = np.array(jnp.any(cand_l, axis=0))
-            needed[seeded] = False
-            n_alive = max(int((np.asarray(self._leaf_count) > 0).sum()), 1)
-            eapca_pr = 1.0 - np.asarray(
-                jnp.sum(cand_l, axis=1), np.float32) / n_alive
+            with span("repro.ooc.select") as sel:
+                slack = jnp.float32(1.0 - cfg.lb_slack)
+                needed, eapca_pr = self._needed_leaves(lbs, ub_d[:, k - 1],
+                                                       slack, seeded)
+                pieces = self._runs(needed, R)
+                sel.set_metadata(runs=len(pieces))
 
             # -- phase 3: LSD sidecar filter, then encoded alive runs ------
-            pieces = self._runs(needed, R)
             use_sax = bool(cfg.use_sax)
             alive_counts = jnp.full((qn,), seed_rows, jnp.int32)
             if not use_sax:
@@ -1147,24 +1169,29 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                 for start, cnt in pieces:
                     lsd_reader.submit(start, cnt, self._pad_bucket(cnt, R))
                 for start, cnt in pieces:
-                    pad_to = self._pad_bucket(cnt, R)
-                    codes = lsd_reader.stage(lsd_reader.get())
-                    ranks = np.zeros((pad_to,), np.int32)
-                    ranks[:cnt] = self._srank[start:start + cnt]
-                    self._t["sax_rows_read"] += cnt
-                    lb_row = jnp.maximum(
-                        kops.lb_sax(q_paa, codes, n, mode=kmode),
-                        lbs[:, ranks])                        # (Q, pad_to)
-                    bsf = ub_d[:, k - 1]
-                    live = ((lb_row * slack < bsf[:, None])
-                            & (jnp.arange(pad_to) < cnt)[None, :])
-                    alive_counts = alive_counts + jnp.sum(live, axis=1,
-                                                          dtype=jnp.int32)
-                    alive = np.asarray(jnp.any(live, axis=0))[:cnt]
+                    with span("repro.ooc.filter", rows=cnt):
+                        pad_to = self._pad_bucket(cnt, R)
+                        codes = lsd_reader.stage(lsd_reader.get())
+                        ranks = np.zeros((pad_to,), np.int32)
+                        ranks[:cnt] = self._srank[start:start + cnt]
+                        self._t["sax_rows_read"] += cnt
+                        lb_row = jnp.maximum(
+                            kops.lb_sax(q_paa, codes, n, mode=kmode),
+                            lbs[:, ranks])                    # (Q, pad_to)
+                        bsf = ub_d[:, k - 1]
+                        live = ((lb_row * slack < bsf[:, None])
+                                & (jnp.arange(pad_to) < cnt)[None, :])
+                        alive_counts = alive_counts + jnp.sum(
+                            live, axis=1, dtype=jnp.int32)
+                        alive = self._syncs.read(
+                            jnp.any(live, axis=0))[:cnt]
+                    with span("repro.ooc.select") as sel:
+                        runs = _alive_runs(alive, start)
+                        sel.set_metadata(runs=len(runs))
                     ub_d, ub_p, lb_d, lb_p = bounds_all(
                         ub_d, ub_p, lb_d, lb_p,
                         [(s0, c0, self._pad_bucket(c0, R))
-                         for s0, c0 in _alive_runs(alive, start)])
+                         for s0, c0 in runs])
         finally:
             self._reap_reader(enc_reader)
             if lsd_reader is not None:
@@ -1233,7 +1260,6 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
         (fetches avoided vs independent queries) and ``wave_rows_shared``
         (rows that served >1 member per single fetch).
         """
-        from repro.core.tree import route_to_leaf
         from repro.data.pipeline import (iter_scheduled_chunks,
                                          make_chunk_reader)
 
@@ -1256,54 +1282,50 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
             # -- phase 1: per-member seed sets, fetched once for the union.
             # Demand = how many members asked for the leaf; popular leaves
             # go first so the shared BSF matrix tightens fastest.
-            lbs = self._leaf_lbs(q)                          # (W, L)
-            home_nodes = route_to_leaf(self.saved.tree, q,
-                                       self.saved.max_depth)
-            home_ranks = np.asarray(self._leaf_rank)[np.asarray(home_nodes)]
-            l_max = min(cfg.l_max, self.saved.num_leaves)
-            _, best = jax.lax.top_k(-lbs, l_max)             # (W, l_max)
-            best_np = np.asarray(best)
-            demand: collections.Counter = collections.Counter()
-            for w in range(qn):
-                member = {int(home_ranks[w])} | {int(r) for r in best_np[w]}
-                for r in member:
-                    if r >= 0 and counts[r] > 0:
-                        demand[r] += 1
-            seeded = sorted(demand)
-            self._t["runs_deduped"] += sum(demand[r] - 1 for r in seeded)
-            self._t["wave_rows_shared"] += sum(
-                int(counts[r]) * (demand[r] - 1) for r in seeded)
-            seed_rows = sum(int(counts[r]) for r in seeded)
-            order = sorted(seeded, key=lambda r: (-demand[r], r))
-            extents = [(int(starts_np[r]), int(counts[r]), max_leaf)
-                       for r in order]
-            for start, cnt, pad_to in extents:
-                lrd_reader.submit(start, cnt, pad_to)
-            for start, cnt, _ in extents:
-                rows = lrd_reader.stage(lrd_reader.get())
-                d, p = _ooc_refine_block(rows, jnp.int32(start),
-                                         jnp.int32(cnt), q, d, p, k=k)
-                self._count(cnt)
+            with span("repro.ooc.seed"):
+                lbs, home_ranks, best_np = self._seed_candidates(q, cfg)
+                demand: collections.Counter = collections.Counter()
+                for w in range(qn):
+                    member = ({int(home_ranks[w])}
+                              | {int(r) for r in best_np[w]})
+                    for r in member:
+                        if r >= 0 and counts[r] > 0:
+                            demand[r] += 1
+                seeded = sorted(demand)
+                self._t["runs_deduped"] += sum(demand[r] - 1 for r in seeded)
+                self._t["wave_rows_shared"] += sum(
+                    int(counts[r]) * (demand[r] - 1) for r in seeded)
+                seed_rows = sum(int(counts[r]) for r in seeded)
+                order = sorted(seeded, key=lambda r: (-demand[r], r))
+                extents = [(int(starts_np[r]), int(counts[r]), max_leaf)
+                           for r in order]
+                for start, cnt, pad_to in extents:
+                    lrd_reader.submit(start, cnt, pad_to)
+                for start, cnt, _ in extents:
+                    rows = lrd_reader.stage(lrd_reader.get())
+                    with span("repro.ooc.refine", rows=cnt):
+                        d, p = _ooc_refine_block(rows, jnp.int32(start),
+                                                 jnp.int32(cnt), q, d, p,
+                                                 k=k)
+                    self._count(cnt)
 
             # -- phase 2: leaf-level pruning, per member -----------------
-            slack = jnp.float32(slack_f)
-            bsf = d[:, k - 1]
-            cand = lbs * slack < bsf[:, None]                # (W, L)
-            needed = np.array(jnp.any(cand, axis=0))
-            needed[seeded] = False
-            n_alive = max(int((counts > 0).sum()), 1)
-            eapca_pr = 1.0 - np.asarray(
-                jnp.sum(cand, axis=1), np.float32) / n_alive
+            with span("repro.ooc.select") as sel:
+                slack = jnp.float32(slack_f)
+                bsf = d[:, k - 1]
+                needed, eapca_pr = self._needed_leaves(lbs, bsf, slack,
+                                                       seeded)
+                pieces = self._runs(needed, R)
+                sel.set_metadata(runs=len(pieces))
 
             # -- phase 3: build the merged alive-run list with a per-member
             # lower bound per run (min over the run's rows/leaves), instead
             # of refining file-order as the per-query path does -----------
-            pieces = self._runs(needed, R)
             use_sax = bool(cfg.use_sax)
             alive_counts = jnp.full((qn,), seed_rows, jnp.int32)
             runs: list[tuple[int, int, np.ndarray]] = []
             if not use_sax:
-                lbs_np = np.asarray(lbs)
+                lbs_np = self._syncs.read(lbs)
                 for start, cnt in pieces:
                     ranks = np.unique(self._srank[start:start + cnt])
                     runs.append((start, cnt, lbs_np[:, ranks].min(axis=1)))
@@ -1317,28 +1339,33 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                 for start, cnt in pieces:
                     lsd_reader.submit(start, cnt, self._pad_bucket(cnt, R))
                 for start, cnt in pieces:
-                    pad_to = self._pad_bucket(cnt, R)
-                    codes = lsd_reader.stage(lsd_reader.get())
-                    ranks = np.zeros((pad_to,), np.int32)
-                    ranks[:cnt] = self._srank[start:start + cnt]
-                    self._t["sax_rows_read"] += cnt
-                    lb_row = jnp.maximum(
-                        kops.lb_sax(q_paa, codes, n, mode=kmode),
-                        lbs[:, ranks])                       # (W, pad_to)
-                    live = ((lb_row * slack < bsf[:, None])
-                            & (jnp.arange(pad_to) < cnt)[None, :])
-                    alive_counts = alive_counts + jnp.sum(live, axis=1,
-                                                          dtype=jnp.int32)
-                    alive = np.asarray(jnp.any(live, axis=0))[:cnt]
-                    lb_np = np.asarray(lb_row)
-                    for s0, c0 in _alive_runs(alive, start):
-                        lo = s0 - start
-                        runs.append((s0, c0,
-                                     lb_np[:, lo:lo + c0].min(axis=1)))
+                    with span("repro.ooc.filter", rows=cnt):
+                        pad_to = self._pad_bucket(cnt, R)
+                        codes = lsd_reader.stage(lsd_reader.get())
+                        ranks = np.zeros((pad_to,), np.int32)
+                        ranks[:cnt] = self._srank[start:start + cnt]
+                        self._t["sax_rows_read"] += cnt
+                        lb_row = jnp.maximum(
+                            kops.lb_sax(q_paa, codes, n, mode=kmode),
+                            lbs[:, ranks])                   # (W, pad_to)
+                        live = ((lb_row * slack < bsf[:, None])
+                                & (jnp.arange(pad_to) < cnt)[None, :])
+                        alive_counts = alive_counts + jnp.sum(
+                            live, axis=1, dtype=jnp.int32)
+                        alive = self._syncs.read(
+                            jnp.any(live, axis=0))[:cnt]
+                        lb_np = self._syncs.read(lb_row)
+                    with span("repro.ooc.select") as sel:
+                        alive_runs = _alive_runs(alive, start)
+                        sel.set_metadata(runs=len(alive_runs))
+                        for s0, c0 in alive_runs:
+                            lo = s0 - start
+                            runs.append((s0, c0,
+                                         lb_np[:, lo:lo + c0].min(axis=1)))
 
             # -- phase 4: fetch each run once, most-demanded first, with a
             # late BSF re-check per submit ---------------------------------
-            bsf_host = {"kth": np.asarray(d[:, k - 1])}
+            bsf_host = {"kth": self._syncs.read(d[:, k - 1])}
 
             def run_demand(run_lb: np.ndarray) -> int:
                 return int((run_lb * slack_f < bsf_host["kth"]).sum())
@@ -1359,10 +1386,11 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                     for s0, c0, run_lb in runs]
             for (s0, c0, _), rows in iter_scheduled_chunks(
                     lrd_reader, reqs, still_needed=still_needed):
-                d, p = _ooc_refine_block(rows, jnp.int32(s0), jnp.int32(c0),
-                                         q, d, p, k=k)
+                with span("repro.ooc.refine", rows=c0):
+                    d, p = _ooc_refine_block(rows, jnp.int32(s0),
+                                             jnp.int32(c0), q, d, p, k=k)
                 self._count(c0)
-                bsf_host["kth"] = np.asarray(d[:, k - 1])
+                bsf_host["kth"] = self._syncs.read(d[:, k - 1])
             self._t["calls"] += 1
             self._t["wave_calls"] += 1
         finally:
@@ -1556,9 +1584,6 @@ class PlanCacheTelemetry(_TelemetrySection):
 @dataclasses.dataclass
 class LatencyTelemetry(_TelemetrySection):
     total: float = 0.0
-    last: float = 0.0
-    mean_per_call: float = 0.0
-    mean_per_query: float = 0.0
 
 
 @dataclasses.dataclass
@@ -1598,6 +1623,29 @@ class OocTelemetry(_TelemetrySection):
     runs_skipped_bsf: int = 0
     codec_refine_rows: int = 0
     codec_fallbacks: int = 0
+    host_syncs: int = 0
+
+
+@dataclasses.dataclass
+class ServingTelemetry(_TelemetrySection):
+    """The slot-based front end's counters
+    (:class:`repro.serve.engine.KnnServeEngine`). ``queue_wait_s`` sums,
+    over the ``dequeued`` requests whose wave was taken, the time from
+    their submit to the start of that wave."""
+    pending: int = 0
+    served: int = 0
+    unclaimed: int = 0
+    batch_slots: int = 0
+    waves: int = 0
+    wave_mode: bool = False
+    pack: str = ""
+    max_queue: int | None = None
+    rejected: int = 0
+    failed: int = 0
+    difficulty_scored: int = 0
+    difficulty_mean: float = 0.0
+    queue_wait_s: float = 0.0
+    dequeued: int = 0
 
 
 @dataclasses.dataclass
@@ -1630,11 +1678,15 @@ class Telemetry(_TelemetrySection):
     """The one serving-telemetry shape (see ``repro.api`` for the key →
     field mapping table). Sections are dataclasses; ``ooc`` is ``None``
     unless the backend streams from disk, ``serving`` is filled by
-    :class:`repro.serve.engine.KnnServeEngine`."""
+    :class:`repro.serve.engine.KnnServeEngine`. ``host_syncs`` counts the
+    blocking device-to-host reads and waits of the layers it covers: the
+    engine's, the backend's, and the front end's where ``serving`` is
+    filled."""
     backend: str = ""
     calls: int = 0
     queries: int = 0
     wave_calls: int = 0
+    host_syncs: int = 0
     plan_cache: PlanCacheTelemetry = dataclasses.field(
         default_factory=PlanCacheTelemetry)
     latency: LatencyTelemetry = dataclasses.field(
@@ -1644,7 +1696,7 @@ class Telemetry(_TelemetrySection):
         default_factory=PruningTelemetry)
     ooc: OocTelemetry | None = None
     dist: DistTelemetry | None = None
-    serving: dict | None = None
+    serving: ServingTelemetry | None = None
 
     _ALIASES = {"latency_s": "latency"}
 
@@ -1672,11 +1724,12 @@ class QueryEngine:
         self.backend = backend
         self.config = config or EngineConfig()
         self._plans: collections.OrderedDict = collections.OrderedDict()
+        self._syncs = HostSyncs()
         self._t = {
             "calls": 0, "queries": 0, "wave_calls": 0,
             "hits": 0, "misses": 0, "evictions": 0,
             "invalidations": 0,
-            "compile_s": 0.0, "exec_s": 0.0, "last_exec_s": 0.0,
+            "compile_s": 0.0, "exec_s": 0.0,
             "paths": np.zeros(4, np.int64), "path_unknown": 0,
             "eapca_pr_sum": 0.0, "sax_pr_sum": 0.0, "stat_queries": 0,
         }
@@ -1737,39 +1790,40 @@ class QueryEngine:
         key = (cfg, bucket, q.shape[1], q.dtype.name, wave,
                getattr(self.backend, "plan_signature", None))
         plan = self._plans.get(key)
-        if plan is None:
-            t0 = time.perf_counter()
-            maker = (self.backend.make_wave_plan if wave
-                     else self.backend.make_plan)
-            plan = maker(cfg, jax.ShapeDtypeStruct(q.shape, q.dtype))
-            self._t["compile_s"] += time.perf_counter() - t0
-            self._t["misses"] += 1
-            self._plans[key] = plan
-            while len(self._plans) > self.config.plan_cache_size:
-                self._plans.popitem(last=False)
-                self._t["evictions"] += 1
-        else:
-            self._t["hits"] += 1
-            self._plans.move_to_end(key)
+        with span("repro.engine.plan", hit=int(plan is not None)):
+            if plan is None:
+                t0 = time.perf_counter()
+                maker = (self.backend.make_wave_plan if wave
+                         else self.backend.make_plan)
+                plan = maker(cfg, jax.ShapeDtypeStruct(q.shape, q.dtype))
+                self._t["compile_s"] += time.perf_counter() - t0
+                self._t["misses"] += 1
+                self._plans[key] = plan
+                while len(self._plans) > self.config.plan_cache_size:
+                    self._plans.popitem(last=False)
+                    self._t["evictions"] += 1
+            else:
+                self._t["hits"] += 1
+                self._plans.move_to_end(key)
 
-        t0 = time.perf_counter()
-        if getattr(plan, "valid_aware", False):
-            # codec plans certify per-query completeness; bucket-padding
-            # rows (sliced away below) must not trip the certify guard
-            res = plan(q, valid_rows=qn)
-        else:
-            res = plan(q)
-        jax.block_until_ready(res.dists)
-        dt = time.perf_counter() - t0
-        self._t["exec_s"] += dt
-        self._t["last_exec_s"] = dt
+        with span("repro.engine.run"):
+            t0 = time.perf_counter()
+            if getattr(plan, "valid_aware", False):
+                # codec plans certify per-query completeness; bucket-padding
+                # rows (sliced away below) must not trip the certify guard
+                res = plan(q, valid_rows=qn)
+            else:
+                res = plan(q)
+            self._syncs.wait(res.dists)
+            self._t["exec_s"] += time.perf_counter() - t0
         self._t["calls"] += 1
         self._t["queries"] += qn
         if wave:
             self._t["wave_calls"] += 1
 
         if bucket != qn:
-            res = KnnResult(*[a[:qn] for a in res])
+            with span("repro.engine.cut"):
+                res = KnnResult(*[a[:qn] for a in res])
         if self.config.collect_result_stats:
             self._record(res)
         return res
@@ -1786,14 +1840,17 @@ class QueryEngine:
         return fn(jnp.asarray(queries))
 
     def _record(self, res: KnnResult) -> None:
-        path = np.asarray(res.path)
-        known = path >= 0
-        self._t["paths"] += np.bincount(path[known], minlength=4)[:4]
-        self._t["path_unknown"] += int((~known).sum())
-        if known.any():
-            self._t["eapca_pr_sum"] += float(np.asarray(res.eapca_pr)[known].sum())
-            self._t["sax_pr_sum"] += float(np.asarray(res.sax_pr)[known].sum())
-            self._t["stat_queries"] += int(known.sum())
+        with span("repro.engine.stats"):
+            path = self._syncs.read(res.path)
+            known = path >= 0
+            self._t["paths"] += np.bincount(path[known], minlength=4)[:4]
+            self._t["path_unknown"] += int((~known).sum())
+            if known.any():
+                self._t["eapca_pr_sum"] += float(
+                    self._syncs.read(res.eapca_pr)[known].sum())
+                self._t["sax_pr_sum"] += float(
+                    self._syncs.read(res.sax_pr)[known].sum())
+                self._t["stat_queries"] += int(known.sum())
 
     # -- introspection ------------------------------------------------------
 
@@ -1817,16 +1874,14 @@ class QueryEngine:
             calls=t["calls"],
             queries=t["queries"],
             wave_calls=t["wave_calls"],
+            host_syncs=self._syncs.count + bstats.get("host_syncs", 0),
             plan_cache=PlanCacheTelemetry(
                 hits=t["hits"], misses=t["misses"],
                 evictions=t["evictions"], size=len(self._plans),
                 capacity=self.config.plan_cache_size,
                 compiles=t["misses"], compile_s=t["compile_s"],
                 invalidations=t["invalidations"]),
-            latency=LatencyTelemetry(
-                total=t["exec_s"], last=t["last_exec_s"],
-                mean_per_call=t["exec_s"] / max(t["calls"], 1),
-                mean_per_query=t["exec_s"] / max(t["queries"], 1)),
+            latency=LatencyTelemetry(total=t["exec_s"]),
             paths=PathsTelemetry(
                 scan_eapca=int(t["paths"][0]),
                 scan_sax=int(t["paths"][1]),

@@ -23,6 +23,13 @@ through ``lax.map`` so the ``lax.cond`` branches stay real branches (the
 paper's "queries run asynchronously"; parallelism lives *inside* a query).
 
 Everything here is exact: all paths return the true k nearest neighbors.
+
+Device scopes: in `exact_knn` and `wave_knn`, phase 1 runs under
+``jax.named_scope("seed")``, phases 2-3 under ``candidates``, and phase 4
+under ``refine`` or ``scan`` (the two branches of `_finish_one`). The names
+ride in each operation's metadata (``op_name``) into the profiler's trace,
+so device time can be put down to a phase; they change nothing else in the
+compiled program.
 """
 from __future__ import annotations
 
@@ -291,65 +298,68 @@ def _query_one(q, tree: HerculesTree, layout: HerculesLayout,
     slack = jnp.float32(1.0 - cfg.lb_slack)
 
     # ---- Phase 1: approximate search (Alg. 11) ----------------------------
-    leaf_lb = _leaf_lbs(q, layout)                   # (L,)
-    home = layout.leaf_rank[route_to_leaf(tree, q[None], max_depth)[0]]
-    _, best_ranks = jax.lax.top_k(-leaf_lb, l_max)
-    visit = jnp.concatenate([home[None].astype(jnp.int32),
-                             best_ranks.astype(jnp.int32)])
+    with jax.named_scope("seed"):
+        leaf_lb = _leaf_lbs(q, layout)                   # (L,)
+        home = layout.leaf_rank[route_to_leaf(tree, q[None], max_depth)[0]]
+        _, best_ranks = jax.lax.top_k(-leaf_lb, l_max)
+        visit = jnp.concatenate([home[None].astype(jnp.int32),
+                                 best_ranks.astype(jnp.int32)])
 
-    d_top = jnp.full((cfg.k,), INF)
-    p_top = jnp.full((cfg.k,), -1, jnp.int32)
+        d_top = jnp.full((cfg.k,), INF)
+        p_top = jnp.full((cfg.k,), -1, jnp.int32)
 
-    def visit_body(carry, rank):
-        d_top, p_top, acc = carry
-        d, pos = _leaf_block_ed(q, layout, rank, max_leaf=layout.max_leaf)
-        d_top, p_top = _merge_topk(d_top, p_top, d, pos, cfg.k)
-        return (d_top, p_top, acc + layout.leaf_count[rank]), None
+        def visit_body(carry, rank):
+            d_top, p_top, acc = carry
+            d, pos = _leaf_block_ed(q, layout, rank, max_leaf=layout.max_leaf)
+            d_top, p_top = _merge_topk(d_top, p_top, d, pos, cfg.k)
+            return (d_top, p_top, acc + layout.leaf_count[rank]), None
 
-    if cfg.unroll_visits:
-        carry = (d_top, p_top, jnp.int32(0))
-        for i in range(l_max + 1):
-            carry, _ = visit_body(carry, visit[i])
-        d_top, p_top, accessed = carry
-    else:
-        (d_top, p_top, accessed), _ = jax.lax.scan(
-            visit_body, (d_top, p_top, jnp.int32(0)), visit)
-    bsf = d_top[cfg.k - 1]
+        if cfg.unroll_visits:
+            carry = (d_top, p_top, jnp.int32(0))
+            for i in range(l_max + 1):
+                carry, _ = visit_body(carry, visit[i])
+            d_top, p_top, accessed = carry
+        else:
+            (d_top, p_top, accessed), _ = jax.lax.scan(
+                visit_body, (d_top, p_top, jnp.int32(0)), visit)
+        bsf = d_top[cfg.k - 1]
 
-    # ---- Phase 2: candidate leaves (Alg. 12) -------------------------------
-    cand_leaf = leaf_lb * slack < bsf                # (L,)
-    n_cand_leaves = jnp.sum(cand_leaf.astype(jnp.int32))
-    n_alive = jnp.maximum(jnp.sum((layout.leaf_count > 0).astype(jnp.int32)), 1)
-    eapca_pr = 1.0 - n_cand_leaves.astype(jnp.float32) / n_alive.astype(jnp.float32)
+    # ---- Phases 2-3: candidate leaves (Alg. 12), then series (Alg. 13) -----
+    with jax.named_scope("candidates"):
+        cand_leaf = leaf_lb * slack < bsf                # (L,)
+        n_cand_leaves = jnp.sum(cand_leaf.astype(jnp.int32))
+        n_alive = jnp.maximum(
+            jnp.sum((layout.leaf_count > 0).astype(jnp.int32)), 1)
+        eapca_pr = 1.0 - (n_cand_leaves.astype(jnp.float32)
+                          / n_alive.astype(jnp.float32))
 
-    # ---- Phase 3: candidate series (Alg. 13) -------------------------------
-    leaf_mask_pad = jnp.concatenate([cand_leaf, jnp.zeros((1,), bool)])
-    series_in_cand = leaf_mask_pad[layout.series_leaf_rank]  # (N_pad,)
+        leaf_mask_pad = jnp.concatenate([cand_leaf, jnp.zeros((1,), bool)])
+        series_in_cand = leaf_mask_pad[layout.series_leaf_rank]  # (N_pad,)
 
-    q_paa = S.paa(q[None], layout.lsd.shape[1])[0]
-    kmode = resolve_kernel_mode(cfg.kernel_mode)
-    if kmode == "ref":
-        lb_s = LB.lb_sax(q_paa, layout.lsd, n)       # (N_pad,)
-    else:
-        # the paper's phase-3 LSDFile stream: the Pallas LB_SAX (MINDIST)
-        # kernel over the whole uint8 sidecar. LB values gate pruning only
-        # (with lb_slack guarding fp32 rounding), so exact answers are
-        # preserved for any kernel arithmetic. The single query row is
-        # padded to the kernel's 8-row minimum tile — on TPU that is free
-        # (the VPU/MXU processes >= 8 sublanes per op regardless), and it
-        # keeps LB memory at (N_pad,) per in-flight query instead of
-        # materializing a (Q, N_pad) matrix outside the lax.map.
-        lb_s = kops.lb_sax(q_paa[None, :], layout.lsd, n, mode=kmode)[0]
-    leaf_lb_pad = jnp.concatenate([leaf_lb, jnp.full((1,), INF)])
-    lb_leaf_series = leaf_lb_pad[layout.series_leaf_rank]
+        q_paa = S.paa(q[None], layout.lsd.shape[1])[0]
+        kmode = resolve_kernel_mode(cfg.kernel_mode)
+        if kmode == "ref":
+            lb_s = LB.lb_sax(q_paa, layout.lsd, n)       # (N_pad,)
+        else:
+            # the paper's phase-3 LSDFile stream: the Pallas LB_SAX (MINDIST)
+            # kernel over the whole uint8 sidecar. LB values gate pruning only
+            # (with lb_slack guarding fp32 rounding), so exact answers are
+            # preserved for any kernel arithmetic. The single query row is
+            # padded to the kernel's 8-row minimum tile — on TPU that is free
+            # (the VPU/MXU processes >= 8 sublanes per op regardless), and it
+            # keeps LB memory at (N_pad,) per in-flight query instead of
+            # materializing a (Q, N_pad) matrix outside the lax.map.
+            lb_s = kops.lb_sax(q_paa[None, :], layout.lsd, n, mode=kmode)[0]
+        leaf_lb_pad = jnp.concatenate([leaf_lb, jnp.full((1,), INF)])
+        lb_leaf_series = leaf_lb_pad[layout.series_leaf_rank]
 
-    if cfg.use_sax:
-        cand_lb = jnp.where(series_in_cand,
-                            jnp.maximum(lb_s, lb_leaf_series), INF)
-    else:
-        cand_lb = jnp.where(series_in_cand, lb_leaf_series, INF)
-    n_cand = jnp.sum((cand_lb * slack < bsf).astype(jnp.int32))
-    sax_pr = 1.0 - n_cand.astype(jnp.float32) / layout.num_series
+        if cfg.use_sax:
+            cand_lb = jnp.where(series_in_cand,
+                                jnp.maximum(lb_s, lb_leaf_series), INF)
+        else:
+            cand_lb = jnp.where(series_in_cand, lb_leaf_series, INF)
+        n_cand = jnp.sum((cand_lb * slack < bsf).astype(jnp.int32))
+        sax_pr = 1.0 - n_cand.astype(jnp.float32) / layout.num_series
 
     # ---- Adaptive access-path selection (Alg. 10) ---------------------------
     d_f, p_f, path, acc_f = _finish_one(
@@ -365,10 +375,12 @@ def _finish_one(q, layout: HerculesLayout, cfg: SearchConfig,
     query — the shared tail of the per-query (`_query_one`) and wave-fused
     (`wave_knn`) pipelines. Returns (dists, positions, path, accessed)."""
 
+    @jax.named_scope("scan")
     def do_scan(_):
         d, p, acc = _scan_path(q, layout, d_top, p_top, cfg)
         return d, p, accessed + acc
 
+    @jax.named_scope("refine")
     def do_refine(_):
         d, p, acc, exhausted = _refine_path(q, layout, cand_lb, d_top, p_top, cfg)
         if cfg.refine_select == "topk":
@@ -473,61 +485,65 @@ def wave_knn(tree: HerculesTree, layout: HerculesLayout, queries: jax.Array,
     n_pad_rows = layout.lrd.shape[0]
 
     # ---- Phase 1: approximate search, wave-fused (Alg. 11) ----------------
-    leaf_lb = _wave_leaf_lbs(queries, layout)            # (W, L)
-    home = layout.leaf_rank[route_to_leaf(tree, queries, max_depth)]
-    _, best = jax.lax.top_k(-leaf_lb, l_max)             # (W, l_max)
-    visit = jnp.concatenate([home[:, None].astype(jnp.int32),
-                             best.astype(jnp.int32)], axis=1)
+    with jax.named_scope("seed"):
+        leaf_lb = _wave_leaf_lbs(queries, layout)            # (W, L)
+        home = layout.leaf_rank[route_to_leaf(tree, queries, max_depth)]
+        _, best = jax.lax.top_k(-leaf_lb, l_max)             # (W, l_max)
+        visit = jnp.concatenate([home[:, None].astype(jnp.int32),
+                                 best.astype(jnp.int32)], axis=1)
 
-    d_top = jnp.full((W, cfg.k), INF)        # the shared per-wave BSF matrix
-    p_top = jnp.full((W, cfg.k), -1, jnp.int32)
-    offs = jnp.arange(layout.max_leaf, dtype=jnp.int32)
-    merge = jax.vmap(functools.partial(_merge_topk, k=cfg.k))
+        d_top = jnp.full((W, cfg.k), INF)    # the shared per-wave BSF matrix
+        p_top = jnp.full((W, cfg.k), -1, jnp.int32)
+        offs = jnp.arange(layout.max_leaf, dtype=jnp.int32)
+        merge = jax.vmap(functools.partial(_merge_topk, k=cfg.k))
 
-    def level_body(carry, ranks):            # ranks: (W,) — one visit level
-        d_top, p_top, acc = carry
-        starts = layout.leaf_start[ranks]
-        cnts = layout.leaf_count[ranks]
-        pos = starts[:, None] + offs[None, :]            # (W, max_leaf)
-        rows = layout.lrd[jnp.clip(pos, 0, n_pad_rows - 1)]  # one gather
-        d = jnp.sum(jnp.square(rows - queries[:, None, :]), axis=2)
-        d = jnp.where(offs[None, :] < cnts[:, None], d, INF)
-        d_top, p_top = merge(d_top, p_top, d, pos)
-        return (d_top, p_top, acc + cnts), None
+        def level_body(carry, ranks):        # ranks: (W,) — one visit level
+            d_top, p_top, acc = carry
+            starts = layout.leaf_start[ranks]
+            cnts = layout.leaf_count[ranks]
+            pos = starts[:, None] + offs[None, :]            # (W, max_leaf)
+            rows = layout.lrd[jnp.clip(pos, 0, n_pad_rows - 1)]  # one gather
+            d = jnp.sum(jnp.square(rows - queries[:, None, :]), axis=2)
+            d = jnp.where(offs[None, :] < cnts[:, None], d, INF)
+            d_top, p_top = merge(d_top, p_top, d, pos)
+            return (d_top, p_top, acc + cnts), None
 
-    (d_top, p_top, accessed), _ = jax.lax.scan(
-        level_body, (d_top, p_top, jnp.zeros((W,), jnp.int32)), visit.T)
-    bsf = d_top[:, cfg.k - 1]
+        (d_top, p_top, accessed), _ = jax.lax.scan(
+            level_body, (d_top, p_top, jnp.zeros((W,), jnp.int32)), visit.T)
+        bsf = d_top[:, cfg.k - 1]
 
-    # ---- Phase 2: candidate leaves (Alg. 12), whole wave at once ----------
-    cand_leaf = leaf_lb * slack < bsf[:, None]           # (W, L)
-    n_cand_leaves = jnp.sum(cand_leaf.astype(jnp.int32), axis=1)
-    n_alive = jnp.maximum(jnp.sum((layout.leaf_count > 0).astype(jnp.int32)), 1)
-    eapca_pr = (1.0 - n_cand_leaves.astype(jnp.float32)
-                / n_alive.astype(jnp.float32))
+    # ---- Phases 2-3: candidate leaves (Alg. 12), then series (Alg. 13),
+    # whole wave at once, one LB_SAX kernel launch -----------------------
+    with jax.named_scope("candidates"):
+        cand_leaf = leaf_lb * slack < bsf[:, None]           # (W, L)
+        n_cand_leaves = jnp.sum(cand_leaf.astype(jnp.int32), axis=1)
+        n_alive = jnp.maximum(
+            jnp.sum((layout.leaf_count > 0).astype(jnp.int32)), 1)
+        eapca_pr = (1.0 - n_cand_leaves.astype(jnp.float32)
+                    / n_alive.astype(jnp.float32))
 
-    # ---- Phase 3: candidate series (Alg. 13), one kernel launch -----------
-    leaf_mask_pad = jnp.concatenate(
-        [cand_leaf, jnp.zeros((W, 1), bool)], axis=1)
-    series_in_cand = leaf_mask_pad[:, layout.series_leaf_rank]   # (W, N_pad)
+        leaf_mask_pad = jnp.concatenate(
+            [cand_leaf, jnp.zeros((W, 1), bool)], axis=1)
+        series_in_cand = leaf_mask_pad[:, layout.series_leaf_rank]  # (W, N_pad)
 
-    q_paa = S.paa(queries, layout.lsd.shape[1])          # (W, m)
-    kmode = resolve_kernel_mode(cfg.kernel_mode)
-    if kmode == "ref":
-        lb_s = jax.lax.map(lambda qp: LB.lb_sax(qp, layout.lsd, n), q_paa)
-    else:
-        lb_s = kops.lb_sax(q_paa, layout.lsd, n, mode=kmode)     # (W, N_pad)
-    leaf_lb_pad = jnp.concatenate([leaf_lb, jnp.full((W, 1), INF)], axis=1)
-    lb_leaf_series = leaf_lb_pad[:, layout.series_leaf_rank]
+        q_paa = S.paa(queries, layout.lsd.shape[1])          # (W, m)
+        kmode = resolve_kernel_mode(cfg.kernel_mode)
+        if kmode == "ref":
+            lb_s = jax.lax.map(lambda qp: LB.lb_sax(qp, layout.lsd, n), q_paa)
+        else:
+            lb_s = kops.lb_sax(q_paa, layout.lsd, n, mode=kmode)  # (W, N_pad)
+        leaf_lb_pad = jnp.concatenate([leaf_lb, jnp.full((W, 1), INF)],
+                                      axis=1)
+        lb_leaf_series = leaf_lb_pad[:, layout.series_leaf_rank]
 
-    if cfg.use_sax:
-        cand_lb = jnp.where(series_in_cand,
-                            jnp.maximum(lb_s, lb_leaf_series), INF)
-    else:
-        cand_lb = jnp.where(series_in_cand, lb_leaf_series, INF)
-    n_cand = jnp.sum((cand_lb * slack < bsf[:, None]).astype(jnp.int32),
-                     axis=1)
-    sax_pr = 1.0 - n_cand.astype(jnp.float32) / layout.num_series
+        if cfg.use_sax:
+            cand_lb = jnp.where(series_in_cand,
+                                jnp.maximum(lb_s, lb_leaf_series), INF)
+        else:
+            cand_lb = jnp.where(series_in_cand, lb_leaf_series, INF)
+        n_cand = jnp.sum((cand_lb * slack < bsf[:, None]).astype(jnp.int32),
+                         axis=1)
+        sax_pr = 1.0 - n_cand.astype(jnp.float32) / layout.num_series
 
     # ---- Phase 4: per-member adaptive refinement (Alg. 10/14) -------------
     def one(args):

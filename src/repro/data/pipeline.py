@@ -31,6 +31,8 @@ reader-side exceptions re-raise at the consumer's ``get()``, and ``close()``
 joins the thread. ``prefetch="sync"`` (:class:`SyncChunkReader`) keeps the
 legacy inline reads behind the same surface and times them, so the two
 modes are directly comparable via ``read_wait_seconds``/``overlap_blocks``.
+Both readers' ``get()`` runs inside a ``repro.ooc.read`` span (argument
+``rows``) and ``stage()`` inside ``repro.ooc.stage``, in the profiler's trace.
 """
 from __future__ import annotations
 
@@ -46,6 +48,8 @@ import numpy as np
 from repro.analysis import sanitize
 
 PREFETCH_MODES = ("sync", "thread")
+
+_span = jax.profiler.TraceAnnotation
 
 
 class DoubleBufferedLoader:
@@ -219,12 +223,13 @@ class SyncChunkReader:
         if not self._reqs:
             raise RuntimeError("get() without a pending submit()")
         start, count, pad_to = self._reqs.popleft()
-        t0 = time.perf_counter()
-        out = np.empty((pad_to, self._width), self._dtype)
-        out[:count] = self._rows[start:start + count]
-        if pad_to > count:
-            out[count:] = 0
-        dt = time.perf_counter() - t0
+        with _span("repro.ooc.read", rows=count):
+            t0 = time.perf_counter()
+            out = np.empty((pad_to, self._width), self._dtype)
+            out[:count] = self._rows[start:start + count]
+            if pad_to > count:
+                out[count:] = 0
+            dt = time.perf_counter() - t0
         self.stats["read_seconds"] += dt
         self.stats["read_wait_seconds"] += dt
         self.stats["blocks"] += 1
@@ -243,11 +248,12 @@ class SyncChunkReader:
         stream to its own mesh device that way) gets its blocks on the
         right device, same as the threaded reader's ``_staged_copy``."""
         del block
-        if device is None:
+        with _span("repro.ooc.stage"):
+            if device is None:
+                # herculint: ok[alias-transfer] -- sync get() returns a fresh buffer per call; nothing refills it, so a zero-copy alias is harmless
+                return jax.device_put(view)
             # herculint: ok[alias-transfer] -- sync get() returns a fresh buffer per call; nothing refills it, so a zero-copy alias is harmless
-            return jax.device_put(view)
-        # herculint: ok[alias-transfer] -- sync get() returns a fresh buffer per call; nothing refills it, so a zero-copy alias is harmless
-        return jax.device_put(view, device)
+            return jax.device_put(view, device)
 
     def close(self) -> None:
         self._closed = True
@@ -381,9 +387,11 @@ class AsyncChunkReader:
             self._recycle(self._held)
             self._held = None
         overlapped = not self._ready.empty()    # read finished before asked
-        t0 = time.perf_counter()
-        sid, n_rows, read_s, exc = self._ready.get()
-        self.stats["read_wait_seconds"] += time.perf_counter() - t0
+        with _span("repro.ooc.read") as sp:
+            t0 = time.perf_counter()
+            sid, n_rows, read_s, exc = self._ready.get()
+            self.stats["read_wait_seconds"] += time.perf_counter() - t0
+            sp.set_metadata(rows=n_rows)
         if exc is not None:
             # the reader thread has exited: latch the failure so later
             # get()/submit() fail loudly instead of blocking forever
@@ -430,9 +438,10 @@ class AsyncChunkReader:
         double-buffer loop uses this to overlap the copy with consumer
         compute."""
         self._consumer.check("stage")
-        dev = _staged_copy(view, device)
-        if block:
-            jax.block_until_ready(dev)
+        with _span("repro.ooc.stage"):
+            dev = _staged_copy(view, device)
+            if block:
+                jax.block_until_ready(dev)
         if self._sanitize and self._held is not None:
             self._staged_tracks.append(
                 (self._held, sanitize.snapshot(view), dev))

@@ -423,6 +423,7 @@ class DistOutOfCoreBackend(_OutOfCoreBase):
             for key, val in sub._t.items():
                 agg[key] = agg.get(key, 0) + val
         agg["calls"] = self._t["calls"]  # one dist call, not one per shard
+        agg["host_syncs"] = sum(sub._syncs.count for sub in self._subs)
         per = lambda key: [sub._t[key] for sub in self._subs]  # noqa: E731
         streamed = per("rows_streamed")
         return {

@@ -118,6 +118,7 @@ def ed_matrix(queries: jax.Array, series: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="ed_matrix",
     )(queries, series)
 
 
@@ -156,5 +157,6 @@ def ed_min(queries: jax.Array, series: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="ed_min",
     )(queries, series)
     return dmin, amin

@@ -105,4 +105,5 @@ def lb_sax_matrix(q_paa: jax.Array, codes: jax.Array, series_len: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="lb_sax",
     )(q_paa.astype(jnp.float32)[:, :, None], codes.T, lo_tab, hi_tab)

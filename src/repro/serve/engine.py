@@ -18,6 +18,7 @@ Both inherit the submit/poll bookkeeping from :class:`SlotQueue`.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -25,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.sanitize import ThreadAffinity
+from repro.core.engine import ServingTelemetry
+from repro.core.probes import HostSyncs, span
 from repro.models import ModelDef
 from repro.models.arch import ArchConfig
 
@@ -249,17 +252,26 @@ class KnnServeEngine(SlotQueue):
     queries are not latency-coupled to expensive wave-mates — while the
     oldest request always ships first, which is the anti-starvation
     guarantee.
+
+    Each request is stamped with ``clock()`` on ``submit``; when its wave
+    is taken, the time from its submit to the wave's start adds to
+    ``serving.queue_wait_s`` and the request to ``serving.dequeued``.
     """
 
-    def __init__(self, engine, cfg: KnnServeConfig | None = None):
+    def __init__(self, engine, cfg: KnnServeConfig | None = None, *,
+                 clock: Callable[[], float] = time.perf_counter):
         super().__init__()
         self.engine = engine
         self.cfg = cfg or KnnServeConfig()
+        self._clock = clock
+        self._syncs = HostSyncs()
         self._rejected = 0
         self._failed = 0
         self._waves = 0
         self._scored = 0
         self._score_sum = 0.0
+        self._queue_wait_s = 0.0
+        self._dequeued = 0
 
     def submit(self, query: np.ndarray, k: int | None = None,
                **overrides: Any) -> int:
@@ -271,7 +283,8 @@ class KnnServeEngine(SlotQueue):
             self._rejected += 1
             raise QueueFull(f"pending queue at max_queue="
                             f"{self.cfg.max_queue}; step() or drain() first")
-        return self._enqueue({"q": q, "k": k, "ov": overrides, "score": None})
+        return self._enqueue({"q": q, "k": k, "ov": overrides, "score": None,
+                              "t": self._clock()})
 
     @staticmethod
     def _sig(r: dict) -> tuple:
@@ -325,41 +338,50 @@ class KnnServeEngine(SlotQueue):
         answered (failures included — each completes as a claimable
         :class:`KnnFailure`). Never livelocks: every selected request
         leaves the queue with a result, success or not."""
-        wave = self._next_wave()
-        if not wave:
-            return 0
-        try:
-            self._serve(wave)
-        except Exception:
-            # head-of-line isolation: one bad request (wrong length, bad
-            # override) must not poison its wave-mates — serve each member
-            # solo, completing the ones that still fail as failures
-            for r in wave:
-                try:
-                    self._serve([r])
-                except Exception as e:
-                    self._failed += 1
-                    self._complete(r["id"],
-                                   KnnFailure(f"{type(e).__name__}: {e}"))
-        self._waves += 1
-        return len(wave)
+        with span("repro.serve.step"):
+            start = self._clock()
+            with span("repro.serve.pack"):
+                wave = self._next_wave()
+            if not wave:
+                return 0
+            self._queue_wait_s += sum(start - r["t"] for r in wave)
+            self._dequeued += len(wave)
+            try:
+                self._serve(wave)
+            except Exception:
+                # head-of-line isolation: one bad request (wrong length, bad
+                # override) must not poison its wave-mates — serve each
+                # member solo, completing the ones that still fail as
+                # failures
+                for r in wave:
+                    try:
+                        self._serve([r])
+                    except Exception as e:
+                        self._failed += 1
+                        self._complete(r["id"],
+                                       KnnFailure(f"{type(e).__name__}: {e}"))
+            self._waves += 1
+            return len(wave)
 
     def _serve(self, wave: list[dict]) -> None:
         slots = self.cfg.batch_slots
         k = wave[0]["k"] if wave[0]["k"] is not None else self.cfg.k
         ov = wave[0]["ov"]
-        q = np.stack([r["q"] for r in wave])
-        if len(wave) < slots:  # pad the partial wave to the slot pool
-            q = np.concatenate(
-                [q, np.zeros((slots - len(wave), q.shape[1]), q.dtype)])
-        res = self.engine.knn(jnp.asarray(q), k=k, valid_rows=len(wave),
+        with span("repro.serve.pack"):
+            q = np.stack([r["q"] for r in wave])
+            if len(wave) < slots:  # pad the partial wave to the slot pool
+                q = np.concatenate(
+                    [q, np.zeros((slots - len(wave), q.shape[1]), q.dtype)])
+            q = jnp.asarray(q)
+        res = self.engine.knn(q, k=k, valid_rows=len(wave),
                               wave=self.cfg.wave, **ov)
-        dists = np.asarray(res.dists)
-        ids = np.asarray(res.ids)
-        paths = np.asarray(res.path)
-        for i, r in enumerate(wave):
-            self._complete(r["id"], KnnAnswer(
-                dists=dists[i], ids=ids[i], path=int(paths[i])))
+        with span("repro.serve.answer"):
+            dists = self._syncs.read(res.dists)
+            ids = self._syncs.read(res.ids)
+            paths = self._syncs.read(res.path)
+            for i, r in enumerate(wave):
+                self._complete(r["id"], KnnAnswer(
+                    dists=dists[i], ids=ids[i], path=int(paths[i])))
 
     def drain(self) -> dict[int, KnnAnswer | KnnFailure]:
         """Serve until the queue is empty; returns (and claims) every
@@ -370,19 +392,23 @@ class KnnServeEngine(SlotQueue):
 
     def telemetry(self):
         """The engine's :class:`repro.core.engine.Telemetry` with the
-        ``serving`` section filled in."""
+        ``serving`` section filled in, and this front end's reads of each
+        wave's answers added to ``host_syncs``."""
         t = self.engine.telemetry()
-        t["serving"] = {"pending": self.pending(),
-                        "served": self._served,
-                        "unclaimed": len(self._results),
-                        "batch_slots": self.cfg.batch_slots,
-                        "waves": self._waves,
-                        "wave_mode": self.cfg.wave,
-                        "pack": self.cfg.pack,
-                        "max_queue": self.cfg.max_queue,
-                        "rejected": self._rejected,
-                        "failed": self._failed,
-                        "difficulty_scored": self._scored,
-                        "difficulty_mean": (self._score_sum
-                                            / max(self._scored, 1))}
+        t.host_syncs += self._syncs.count
+        t.serving = ServingTelemetry(
+            pending=self.pending(),
+            served=self._served,
+            unclaimed=len(self._results),
+            batch_slots=self.cfg.batch_slots,
+            waves=self._waves,
+            wave_mode=self.cfg.wave,
+            pack=self.cfg.pack,
+            max_queue=self.cfg.max_queue,
+            rejected=self._rejected,
+            failed=self._failed,
+            difficulty_scored=self._scored,
+            difficulty_mean=self._score_sum / max(self._scored, 1),
+            queue_wait_s=self._queue_wait_s,
+            dequeued=self._dequeued)
         return t
