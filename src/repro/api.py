@@ -64,8 +64,12 @@ dict keys keep working as deprecated aliases:
 old dict access                         Telemetry field
 ======================================  ===================================
 ``t["backend"] / ["calls"] /``          same-named top-level fields
-``["queries"] / ["wave_calls"] /``      (``host_syncs``: blocking device→
-``["host_syncs"]``                      host reads and waits)
+``["queries"] / ["rows_skipped"] /``    (``host_syncs``: blocking device→
+``["wave_calls"] / ["host_syncs"]``     host reads and waits;
+                                        ``rows_skipped``: padding rows a
+                                        plan skipped, so the skip share is
+                                        ``rows_skipped / (queries +
+                                        rows_skipped)``)
 ``t["plan_cache"]["hits" | ...]``       ``t.plan_cache.hits`` ...
 ``t["latency_s"]["total"]``             ``t.latency.total`` (seconds in
                                         the plans, waits included)

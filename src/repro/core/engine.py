@@ -193,10 +193,19 @@ class LocalBackend(BackendBase):
         return lambda q: exact_knn(idx.tree, idx.layout, q, cfg, idx.max_depth)
 
     def make_plan(self, cfg, q_struct):
+        """One program for every fill of the bucket: the count of real rows
+        is a traced scalar, and the padded slots skip the pipeline."""
         idx = self.index
         compiled = exact_knn.lower(
-            idx.tree, idx.layout, q_struct, cfg, idx.max_depth).compile()
-        return lambda q: compiled(idx.tree, idx.layout, q)
+            idx.tree, idx.layout, q_struct, cfg, idx.max_depth,
+            jax.ShapeDtypeStruct((), jnp.int32)).compile()
+
+        def run(q, valid_rows=None):
+            n = q.shape[0] if valid_rows is None else valid_rows
+            return compiled(idx.tree, idx.layout, q, np.int32(n))
+
+        run.valid_aware = run.skips_padding = True
+        return run
 
     def make_wave_plan(self, cfg, q_struct):
         idx = self.index
@@ -1681,10 +1690,12 @@ class Telemetry(_TelemetrySection):
     :class:`repro.serve.engine.KnnServeEngine`. ``host_syncs`` counts the
     blocking device-to-host reads and waits of the layers it covers: the
     engine's, the backend's, and the front end's where ``serving`` is
-    filled."""
+    filled. ``rows_skipped`` counts the padding rows of a batch that the
+    plan skipped (the local plan runs no pipeline for them)."""
     backend: str = ""
     calls: int = 0
     queries: int = 0
+    rows_skipped: int = 0
     wave_calls: int = 0
     host_syncs: int = 0
     plan_cache: PlanCacheTelemetry = dataclasses.field(
@@ -1726,7 +1737,7 @@ class QueryEngine:
         self._plans: collections.OrderedDict = collections.OrderedDict()
         self._syncs = HostSyncs()
         self._t = {
-            "calls": 0, "queries": 0, "wave_calls": 0,
+            "calls": 0, "queries": 0, "rows_skipped": 0, "wave_calls": 0,
             "hits": 0, "misses": 0, "evictions": 0,
             "invalidations": 0,
             "compile_s": 0.0, "exec_s": 0.0,
@@ -1809,8 +1820,9 @@ class QueryEngine:
         with span("repro.engine.run"):
             t0 = time.perf_counter()
             if getattr(plan, "valid_aware", False):
-                # codec plans certify per-query completeness; bucket-padding
-                # rows (sliced away below) must not trip the certify guard
+                # the local plan skips the padding rows (sliced away below);
+                # codec plans certify per-query completeness, and those rows
+                # must not trip the certify guard
                 res = plan(q, valid_rows=qn)
             else:
                 res = plan(q)
@@ -1818,6 +1830,8 @@ class QueryEngine:
             self._t["exec_s"] += time.perf_counter() - t0
         self._t["calls"] += 1
         self._t["queries"] += qn
+        if getattr(plan, "skips_padding", False):
+            self._t["rows_skipped"] += bucket - qn
         if wave:
             self._t["wave_calls"] += 1
 
@@ -1873,6 +1887,7 @@ class QueryEngine:
             backend=self.backend.name,
             calls=t["calls"],
             queries=t["queries"],
+            rows_skipped=t["rows_skipped"],
             wave_calls=t["wave_calls"],
             host_syncs=self._syncs.count + bstats.get("host_syncs", 0),
             plan_cache=PlanCacheTelemetry(

@@ -410,13 +410,34 @@ def _finish_one(q, layout: HerculesLayout, cfg: SearchConfig,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "max_depth"))
 def exact_knn(tree: HerculesTree, layout: HerculesLayout, queries: jax.Array,
-              cfg: SearchConfig, max_depth: int) -> KnnResult:
-    """Exact kNN for a workload of queries (Q, n). See module docstring."""
+              cfg: SearchConfig, max_depth: int,
+              n_valid: jax.Array | None = None) -> KnnResult:
+    """Exact kNN for a workload of queries (Q, n). See module docstring.
+
+    ``n_valid``: the count of leading real rows in a padded batch, a traced
+    int32 scalar (one program serves every fill). Each slot from ``n_valid``
+    on skips the per-query pipeline and holds a placeholder: dists ``+inf``,
+    positions, ids and path ``-1``, pruning ratios and counts 0. The real
+    rows run the same ``_query_one``. ``None`` means every row is real."""
 
     def one(q):
         return _query_one(q, tree, layout, cfg, max_depth)
 
-    d, p, path, e_pr, s_pr, acc, vis = jax.lax.map(one, queries)
+    def skipped(q):
+        return (jnp.full((cfg.k,), INF), jnp.full((cfg.k,), -1, jnp.int32),
+                jnp.int32(-1), jnp.float32(0), jnp.float32(0), jnp.int32(0),
+                jnp.int32(0))
+
+    def slot(args):
+        i, q = args
+        return jax.lax.cond(i < n_valid, one, skipped, q)
+
+    if n_valid is None:
+        out = jax.lax.map(one, queries)
+    else:
+        slots = jnp.arange(queries.shape[0], dtype=jnp.int32)
+        out = jax.lax.map(slot, (slots, queries))
+    d, p, path, e_pr, s_pr, acc, vis = out
     safe_p = jnp.clip(p, 0, layout.perm.shape[0] - 1)
     ids = jnp.where(p >= 0, layout.perm[safe_p], -1)
     return KnnResult(dists=d, positions=p, ids=ids, path=path,

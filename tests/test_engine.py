@@ -366,3 +366,32 @@ class TestKnnServeEngine:
                                    rtol=1e-3, atol=1e-3)
         sv = serve.telemetry()["serving"]
         assert sv["pack"] == "difficulty" and sv["difficulty_scored"] == 10
+
+    @pytest.mark.parametrize("k", [1, K])
+    def test_partial_waves_skip_padded_slots(self, data, k):
+        """Waves of every fill share one plan per k; the plan skips the
+        padding slots, and every answer stays exact."""
+        slots = 32
+        eng = QueryEngine(LocalBackend(HerculesIndex.build(data, CFG)))
+        serve = KnnServeEngine(eng, KnnServeConfig(batch_slots=slots, k=k))
+        fills = range(1, slots + 1)
+        workload = np.asarray(make_query_workload(
+            jax.random.PRNGKey(14), data, sum(fills), "5%"))
+        rids, start = [], 0
+        for fill in fills:
+            rids += [serve.submit(q) for q in workload[start:start + fill]]
+            start += fill
+            assert serve.step() == fill
+        answers = serve.drain()
+        t = serve.telemetry()
+        pc = t.plan_cache
+        assert (pc.misses, pc.compiles, pc.hits) == (1, 1, slots - 1)
+        assert t.queries == len(workload)
+        assert t.rows_skipped == sum(slots - f for f in fills)
+        assert t["rows_skipped"] == t.rows_skipped
+        bf_d, bf_i = brute_force_knn(data, jnp.asarray(workload), k)
+        got_d = np.stack([answers[r].dists for r in rids])
+        got_i = np.stack([answers[r].ids for r in rids])
+        np.testing.assert_allclose(got_d, np.asarray(bf_d),
+                                   rtol=1e-3, atol=1e-3)
+        np.testing.assert_array_equal(got_i, np.asarray(bf_i))

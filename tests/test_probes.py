@@ -42,11 +42,15 @@ def queries(data):
                                           "5%"))
 
 
-@pytest.mark.parametrize("plan", [exact_knn, wave_knn])
-def test_lowered_plan_carries_phase_scopes(index, plan):
+@pytest.mark.parametrize("plan, n_valid", [
+    (exact_knn, False), (wave_knn, False), (exact_knn, True)],
+    ids=["exact_knn", "wave_knn", "exact_knn-n_valid"])
+def test_lowered_plan_carries_phase_scopes(index, plan, n_valid):
     q = jax.ShapeDtypeStruct((SLOTS, LEN), jnp.float32)
+    # the engine's local plan: the padded slots' skip is a lax.cond
+    extra = (jax.ShapeDtypeStruct((), jnp.int32),) if n_valid else ()
     lowered = plan.lower(index.tree, index.layout, q, CFG.search,
-                         index.max_depth)
+                         index.max_depth, *extra)
     text = lowered.as_text(debug_info=True)
     # the compiled program's op metadata is what the profiler's trace reads
     op_names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())

@@ -12,7 +12,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import (BuildConfig, HerculesIndex, IndexConfig, SearchConfig,
-                        brute_force_knn, pscan_knn)
+                        brute_force_knn, exact_knn, pscan_knn)
 from repro.data import make_query_workload, random_walks
 
 jax.config.update("jax_platform_name", "cpu")
@@ -200,3 +200,41 @@ class TestTopkRefine:
         res = idx.knn(q, refine_select="topk", topk_budget_chunks=1,
                       adaptive=False)
         _assert_exact(res, data, q, 5)
+
+
+class TestPaddedSlots:
+    """``exact_knn(..., n_valid=)``: rows past the count skip the pipeline."""
+
+    Q = 8
+
+    @pytest.fixture(scope="class")
+    def workload(self, default_index):
+        data, idx = default_index
+        # easy and out-of-distribution rows: both access paths run
+        q = jnp.concatenate([
+            make_query_workload(jax.random.PRNGKey(31), data, 4, "1%"),
+            make_query_workload(jax.random.PRNGKey(32), data, 4, "ood")])
+        full = exact_knn(idx.tree, idx.layout, q, idx.config.search,
+                         idx.max_depth)
+        return q, full
+
+    @pytest.mark.parametrize("n_valid", [1, Q // 2, Q, None])
+    def test_real_rows_bitwise_padded_rows_placeholder(self, default_index,
+                                                       workload, n_valid):
+        _, idx = default_index
+        q, full = workload
+        res = exact_knn(idx.tree, idx.layout, q, idx.config.search,
+                        idx.max_depth,
+                        None if n_valid is None else jnp.int32(n_valid))
+        n = self.Q if n_valid is None else n_valid
+        assert {int(p) for p in np.asarray(full.path)} >= {0, 2}
+        for name, got, want in zip(res._fields, res, full):
+            got, want = np.asarray(got), np.asarray(want)
+            assert got.shape == want.shape and got.dtype == want.dtype, name
+            np.testing.assert_array_equal(got[:n], want[:n], err_msg=name)
+        pad = slice(n, None)
+        assert np.all(np.isposinf(np.asarray(res.dists)[pad]))
+        for name in ("positions", "ids", "path"):
+            assert np.all(np.asarray(getattr(res, name))[pad] == -1), name
+        for name in ("eapca_pr", "sax_pr", "accessed", "visited_leaves"):
+            assert np.all(np.asarray(getattr(res, name))[pad] == 0), name
