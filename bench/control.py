@@ -54,14 +54,8 @@ def control_checks(cell: dict, seed: int, seconds: float) -> dict:
     data = synth.collection(schedule.prng_key(seed), cfg["num_series"],
                             cfg["series_len"])
     reqs = schedule.make_requests(traffic, seed, seconds, data,
-                                  count=loop.count(traffic))
-    rows = range(len(reqs))
-    if reqs.due is None:
-        # a closed loop's window answers what it reaches; a run reaches
-        # some hundreds of requests, so the control answers as many
-        rows = range(min(len(reqs), int(traffic.get("control_requests",
-                                                    512))))
-    rows = list(rows)
+                                  count=loop.count(traffic, seconds))
+    rows = list(range(len(reqs)))
     answers = [None] * len(rows)
     ks = [reqs.k[i] for i in rows]
     queries = reqs.queries[rows]

@@ -77,7 +77,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, *,
     with setup_part("data"):
         data = synth.collection(key, cfg["num_series"], cfg["series_len"])
         reqs = schedule.make_requests(traffic, seed, seconds, data,
-                                      count=loop.count(traffic))
+                                      count=loop.count(traffic, seconds))
         warm = None if rehearse else schedule.make_requests(
             traffic, seed, seconds, data,
             count=loop.warm_count(traffic, slots), stream=1)
@@ -113,8 +113,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, *,
         srv = dep = None
         gc.collect()
     emit({"window_s": window.end, "waves": len(window.waves),
-          "requests": len(window.rows), "withdrawn": window.withdrawn,
-          "window_compiles": compiles, **window.lateness(), **profiler})
+          "requests": len(window.rows), "window_compiles": compiles,
+          **loop.end_to_end(window), **window.lateness(), **profiler})
 
     t_ref = clock()
     with span("bench.reference"):
@@ -171,7 +171,7 @@ def warm_all(srv, loop, traffic: dict, reqs, seconds: float, slots: int,
     ``reqs``."""
     if traffic.get("rehearse"):
         with part("rehearsal"):
-            loop.rehearse(srv, reqs, traffic, seconds, clock, meter)
+            loop.rehearse(srv, reqs, traffic, seconds, clock)
     else:
         warm_up(srv, reqs, slots, part, meter)
 

@@ -76,5 +76,5 @@ def warm_count(traffic: dict, slots: int) -> int:
     return int(traffic["warmup_waves"]) * slots * len(traffic["k"])
 
 
-def count(traffic: dict) -> int | None:
+def count(traffic: dict, seconds: float) -> int | None:
     return None          # the rate and the window's length set it

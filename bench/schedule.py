@@ -6,6 +6,9 @@ inter-arrival gaps are the same set of exponential quantiles, each list
 shuffled by its own stream of the seed. The hardness and the k of a request
 are drawn independently. What the seed changes beyond the order is which
 collection series a query perturbs and its noise.
+
+A closed loop fixes even that: its requests are one set in one order, the
+same for every seed (``fixed_set``).
 """
 from __future__ import annotations
 
@@ -66,19 +69,25 @@ def arrivals(rate: float, seconds: float, rng: np.random.Generator):
 def make_requests(traffic: dict, seed: int, seconds: float, data,
                   count: int | None = None, stream: int = 0) -> Requests:
     """The run's requests: open loops (``rate_per_s`` in the traffic) get
-    due times over ``seconds``; closed loops get ``count`` requests (the
-    traffic's ``pool``). ``stream`` separates warm-up requests from timed
-    ones under the same seed."""
-    rng_order = np.random.default_rng([seed, stream, 1])
-    rng_k = np.random.default_rng([seed, stream, 2])
-    rng_due = np.random.default_rng([seed, stream, 3])
+    due times over ``seconds``; closed loops get ``count`` requests, one
+    set in one order for every seed (``fixed_set``). ``stream`` separates
+    warm-up requests from timed ones under the same seed."""
+    if "rate_per_s" not in traffic:
+        return fixed_set(traffic, data.shape, count, stream)
     due = None
     if count is None:
-        if "rate_per_s" in traffic:
-            due = arrivals(float(traffic["rate_per_s"]), seconds, rng_due)
-            count = len(due)
-        else:
-            count = int(traffic["pool"])
+        rng_due = np.random.default_rng([seed, stream, 3])
+        due = arrivals(float(traffic["rate_per_s"]), seconds, rng_due)
+        count = len(due)
+    return _draw(traffic, seed, data, count, stream, due)
+
+
+def _draw(traffic: dict, seed: int, data, count: int, stream: int,
+          due: np.ndarray | None) -> Requests:
+    """``count`` requests of the traffic's hardness and k mix, drawn from
+    ``seed`` against the series of ``data``."""
+    rng_order = np.random.default_rng([seed, stream, 1])
+    rng_k = np.random.default_rng([seed, stream, 2])
     hardness = _equal_shares(list(traffic["hardness"]), count, rng_order)
     ks = _equal_shares([int(k) for k in traffic["k"]], count, rng_k)
     queries = np.zeros((count, data.shape[1]), np.float32)
@@ -91,3 +100,20 @@ def make_requests(traffic: dict, seed: int, seconds: float, data,
             queries[rows] = np.asarray(q)
     return Requests(hardness=tuple(hardness), k=tuple(ks), due=due,
                     queries=queries)
+
+
+def fixed_set(traffic: dict, shape: tuple, count: int,
+              stream: int = 0) -> Requests:
+    """``count`` requests that are one set, in one order, for every seed.
+
+    Which series a query perturbs decides how much of an out-of-core
+    collection it streams, and which requests share a wave decides how
+    much a wave streams for all of them (PERF.md), so a set or an order
+    drawn per seed would change a closed loop's work from seed to seed.
+    The set is drawn from ``synth.QUERY_SET_SEED`` against the
+    collection's series in their own order (``synth.series_set``), with
+    the hardness levels and k values in the order that seed draws, as a
+    query workload file holds them. The run's seed orders the collection,
+    and with it every id the answers carry."""
+    return _draw(traffic, synth.QUERY_SET_SEED, synth.series_set(*shape),
+                 count, stream, None)

@@ -12,6 +12,8 @@ that a change to the program cannot change the yardstick.
   counts from the set, and the program bakes them into its shapes, so a
   set that changed with the seed would compile the build and the plans
   again in every run.
+* ``series_set``: that fixed set in its own order, for traffic whose
+  queries have to be the same for every seed (``bench/schedule.py``).
 * ``noisy_queries``: the hardness protocol. A noise level "p%" picks
   collection series at random and adds N(0, p/100) noise to each point;
   ``ood`` draws fresh random walks, which the collection does not hold.
@@ -26,6 +28,7 @@ import jax.numpy as jnp
 HARDNESS = ("1%", "2%", "5%", "10%", "ood")
 SLAB = 1 << 18
 COLLECTION_SEED = 20221001        # the one set of series every seed reorders
+QUERY_SET_SEED = 20221002         # a closed loop's one set of requests
 
 
 def random_walks(key: jax.Array, num: int, length: int) -> jax.Array:
@@ -54,13 +57,24 @@ def _fill_in_order(order_key: jax.Array, *, num: int, length: int,
     return data[jax.random.permutation(order_key, num)]
 
 
-def collection(key: jax.Array, num: int, length: int) -> jax.Array:
-    """(num, length) float32 Synth collection, made on the device: the
-    fixed set of ``num`` series, in the order ``key`` draws."""
+def _slab(num: int) -> int:
     slab = min(SLAB, num)
     if num % slab:
         raise ValueError(f"num={num} is not a multiple of the slab {slab}")
-    return _fill_in_order(key, num=num, length=length, slab=slab)
+    return slab
+
+
+def collection(key: jax.Array, num: int, length: int) -> jax.Array:
+    """(num, length) float32 Synth collection, made on the device: the
+    fixed set of ``num`` series, in the order ``key`` draws."""
+    return _fill_in_order(key, num=num, length=length, slab=_slab(num))
+
+
+def series_set(num: int, length: int) -> jax.Array:
+    """The fixed set of ``num`` series that ``collection`` reorders, in
+    its own order."""
+    return _fill(jax.random.PRNGKey(COLLECTION_SEED), num=num, length=length,
+                 slab=_slab(num))
 
 
 @functools.partial(jax.jit, static_argnames=("num", "hardness"))

@@ -6,7 +6,6 @@ import pytest
 
 import bench.control
 from bench import harness, reference, registry, schedule, synth
-from bench.tests import tiny
 from bench.tests.tiny import tiny_cell
 
 CELLS = [w["name"] for w in registry.benchmark()["workloads"]]
@@ -27,9 +26,9 @@ def answers_of(data, queries, ks, precision):
     return out
 
 
-@pytest.mark.parametrize("name", CELLS + tiny.DEFERRED_CELLS)
+@pytest.mark.parametrize("name", CELLS)
 def test_control_fails_every_cell(data, name):
-    cell = tiny.cell(name)
+    cell = registry.cell(name)
     limits = cell["config"]["limits"]
     reqs = schedule.make_requests(cell["traffic"], 4, 2.0, data, count=48)
     ks = list(reqs.k)
@@ -51,8 +50,8 @@ def test_control_tool_at_test_size():
 
 
 def test_wrong_ids_and_missing_answers_read_high(data):
-    reqs = schedule.make_requests({"hardness": ["1%"], "k": [10], "pool": 8},
-                                  9, 1.0, data)
+    reqs = schedule.make_requests({"hardness": ["1%"], "k": [10]}, 9, 1.0,
+                                  data, count=8)
     ks = list(reqs.k)
     good = answers_of(data, reqs.queries, ks, "float32")
     d, i = good[0]

@@ -8,7 +8,6 @@ import re
 import pytest
 
 from bench import registry
-from bench.tests import tiny
 
 BENCH = registry.benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -16,9 +15,9 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 
 
-@pytest.mark.parametrize("name", CELLS + tiny.DEFERRED_CELLS)
+@pytest.mark.parametrize("name", CELLS)
 def test_cell_found_by_name(name):
-    cell = tiny.cell(name)
+    cell = registry.cell(name)
     cfg, traffic = cell["config"], cell["traffic"]
     registry.load_module("deploy", cfg["deployment"])
     loop = registry.load_module("loops", traffic["loop"])

@@ -1,6 +1,8 @@
 """The arrival schedule and the query set are fixed by the seed, and every
-seed gets the same sizes and arrivals in another order."""
+seed gets the same sizes and arrivals in another order; a closed loop gets
+one set of requests in another order."""
 import collections
+import hashlib
 
 import jax
 import numpy as np
@@ -67,8 +69,11 @@ def test_warm_up_stream_differs(data):
 
 
 def test_closed_pool(data):
-    reqs = schedule.make_requests(dict(BATCH, pool=96), 4, 5.0, data)
-    assert reqs.due is None and len(reqs) == 96
+    loop = registry.load_module("loops", BATCH["loop"])
+    count = loop.count(BATCH, 5.0)
+    assert count == round(BATCH["requests_per_window_second"] * 5.0)
+    reqs = schedule.make_requests(BATCH, 4, 5.0, data, count=count)
+    assert reqs.due is None and len(reqs) == count
 
 
 def test_queries_follow_hardness(data):
@@ -88,3 +93,74 @@ def test_large_seeds_keep_their_bits(seed):
     assert len(keys) == (1 if seed == 5 else 2)
     with pytest.raises(ValueError):
         schedule.prng_key(-1)
+
+
+def _longest_run(values) -> int:
+    best = run = 1
+    for prev, cur in zip(values, values[1:]):
+        run = run + 1 if cur == prev else 1
+        best = max(best, run)
+    return best
+
+
+@pytest.mark.parametrize("seeds", [(4, 5), (11, 2**33 + 7)])
+def test_fixed_set_is_the_same_for_every_seed(data, seeds):
+    # disk-easy-batch: every seed gets the same requests in the same order,
+    # with equal hardness and k shares mixed as a workload file holds them,
+    # not in runs that match a wave
+    a, b = (schedule.make_requests(BATCH, s, 20.0, data, count=340)
+            for s in seeds)
+    assert len(a) == len(b) == 340 and a.due is None
+    assert a.k == b.k and a.hardness == b.hardness
+    np.testing.assert_array_equal(a.queries, b.queries)
+    for values, field in ((BATCH["hardness"], a.hardness), (BATCH["k"], a.k)):
+        counts = collections.Counter(field)
+        assert set(counts) == set(values)
+        assert max(counts.values()) - min(counts.values()) < len(values)
+        assert _longest_run(field) < 16
+
+
+def test_fixed_set_ignores_the_collection_order():
+    # the set is drawn against the series in their own order, so the seed's
+    # order of the collection does not change it; a query still perturbs a
+    # series of the collection
+    a, b = (schedule.make_requests(BATCH, 4, 20.0,
+                                   synth.collection(schedule.prng_key(s),
+                                                    4096, 256), count=16)
+            for s in (1, 2))
+    np.testing.assert_array_equal(a.queries, b.queries)
+    host = np.asarray(synth.collection(schedule.prng_key(1), 4096, 256))
+    for q in a.queries:
+        assert np.min(np.sum((host - q) ** 2, axis=1)) < 0.1 * 256
+
+
+GOLDEN_OPEN = {
+    7: "135de5227c95c1d95b671b4a8df045735dcbde048b076155b8177850c54dede0",
+    2**31 + 17:
+        "5e24bbb17ee882c0b6dbd6118121fb1290e66241644f15ba8bf79d403f107214",
+    5_000_000_123:
+        "e61862afed8bdd0cdcfb9913131a0fb44734f1b6338a7aeec2cba9dc7de5b568",
+}
+
+
+@pytest.mark.parametrize("seed", sorted(GOLDEN_OPEN))
+def test_hbm_easy_open_inputs_unchanged(seed):
+    # hbm-easy-open's collection, timed requests and warm-up requests at
+    # the test size, hashed as the benchmark first made them
+    from bench.tests.tiny import tiny_cell
+
+    cell = tiny_cell("hbm-easy-open")
+    cfg, traffic = cell["config"], cell["traffic"]
+    loop = registry.load_module("loops", traffic["loop"])
+    data = synth.collection(schedule.prng_key(seed), cfg["num_series"],
+                            cfg["series_len"])
+    h = hashlib.sha256(np.asarray(data).tobytes())
+    for count, stream in ((loop.count(traffic, 20.0), 0),
+                          (loop.warm_count(traffic, 32), 1)):
+        reqs = schedule.make_requests(traffic, seed, 20.0, data, count=count,
+                                      stream=stream)
+        h.update(repr((reqs.hardness, reqs.k)).encode())
+        if reqs.due is not None:
+            h.update(np.asarray(reqs.due, np.float64).tobytes())
+        h.update(np.asarray(reqs.queries).tobytes())
+    assert h.hexdigest() == GOLDEN_OPEN[seed]

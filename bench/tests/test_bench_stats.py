@@ -9,6 +9,7 @@ from bench.loops import closed, open_poisson
 from bench.schedule import Requests
 from bench.serving import Server
 from bench.window import Window, percentile
+from repro.api import KnnResult, KnnServeConfig, KnnServeEngine
 
 
 class Clock:
@@ -123,15 +124,16 @@ def test_percentile_is_over_all_requests():
 def closed_window(**fake):
     clock = Clock()
     srv = Server(FakeServer(clock, **fake), FakeEngine())
-    return closed.run(srv, requests(1000), {"outstanding": 8}, 2.0, clock)
+    return closed.run(srv, requests(80), {"outstanding": 8}, 2.0, clock)
 
 
 def test_closed_loop_rate_over_whole_window():
     w = closed_window(wave_s=0.1, slots=4)
     m = closed.end_to_end(w)
-    # 4 requests per 0.1 s wave, 20 waves in the 2 s window
+    # 4 requests per 0.1 s wave: the set of 80 takes 20 waves, 2 s
     assert m["queries_per_s"] == pytest.approx(40.0)
-    assert w.withdrawn == 8 and w.end == pytest.approx(2.0)
+    assert w.answered.all() and len(w.rows) == 80
+    assert w.end == pytest.approx(2.0) and len(w.waves) == 20
 
 
 def test_a_stall_moves_the_rate():
@@ -149,7 +151,7 @@ def test_closed_loop_stall_counts_missing():
     clock = Clock()
     srv = Server(Stuck(clock), FakeEngine())
     w = closed.run(srv, requests(100), {"outstanding": 8}, 1.0, clock)
-    assert w.failed == 8 and w.withdrawn == 0
+    assert w.failed == 8 and len(w.rows) == 8
 
 
 def test_percentile_needs_values():
@@ -158,13 +160,20 @@ def test_percentile_needs_values():
 
 
 def test_open_loop_metrics_listed_for_its_cells():
+    # every end-to-end metric but set-up comes from the loop of each cell
+    # that lists it
     bench = registry.benchmark()
-    for name in ("latency_p50_ms", "latency_p95_ms"):
-        m = [e for e in bench["end_to_end"] if e["name"] == name][0]
+    w = Window(seconds=1.0, end=1.0, due=np.zeros(4), submit=np.zeros(4),
+               start=np.zeros(4), done=np.full(4, 0.5), answers=[(1, 1)] * 4,
+               waves=[], before={}, rows=np.arange(4))
+    for m in bench["end_to_end"]:
+        if m["name"] == "setup_s":
+            continue
         for cell in m["workloads"]:
-            traffic = [w for w in bench["workloads"] if w["name"] == cell][0]
-            assert registry.load_json("traffic", traffic["traffic"])[
-                "loop"] == "open_poisson"
+            traffic = [c for c in bench["workloads"] if c["name"] == cell][0]
+            loop = registry.load_module("loops", registry.load_json(
+                "traffic", traffic["traffic"])["loop"])
+            assert m["name"] in loop.end_to_end(w), (m["name"], cell)
 
 
 class ByK(FakeServer):
@@ -195,45 +204,90 @@ class ByK(FakeServer):
         return len(wave)
 
 
-class FirstWavesCompile:
-    """A compile meter on which the server's first ``n`` waves compile."""
+class CostByK(ByK):
+    """``ByK`` whose waves of k = 10 take ``slow`` times those of k = 1."""
 
-    def __init__(self, server, n):
-        self.server, self.n = server, n
+    def __init__(self, clock, slow=3.5, **kw):
+        super().__init__(clock, **kw)
+        self.slow = slow
 
-    def mark(self):
-        return self.server.waves
+    def step(self):
+        k = self.k[self.queue[0]] if self.queue else None
+        served = super().step()
+        if k == 10:
+            self.clock.t += (self.slow - 1) * self.wave_s
+        return served
 
-    def since(self, mark):
-        return {"compiled": max(0, min(self.server.waves, self.n) - mark)}
+
+def mixed_k(n, seed=3):
+    ks = np.random.default_rng(seed).choice([1, 10], n)
+    return Requests(hardness=("1%",) * n, k=tuple(int(k) for k in ks),
+                    due=None,
+                    queries=np.repeat(np.arange(n, dtype=np.float32)[:, None],
+                                      4, axis=1))
 
 
 def test_rehearsal_serves_the_windows_waves():
     clock = Clock()
     fake = ByK(clock, wave_s=0.1, slots=4)
     srv = Server(fake, FakeEngine())
-    n = 1000
-    reqs = Requests(hardness=("1%",) * n,
-                    k=tuple(10 if i % 3 else 1 for i in range(n)), due=None,
-                    queries=np.repeat(np.arange(n, dtype=np.float32)[:, None],
-                                      4, axis=1))
+    reqs = mixed_k(300)
     traffic = {"outstanding": 8}
-    closed.rehearse(srv, reqs, traffic, 2.0, clock, FirstWavesCompile(fake, 3))
+    waves = closed.rehearse(srv, reqs, traffic, 2.0, clock)
     assert srv.outstanding() == 0
     rehearsed, fake.log = fake.log, []
-    closed.run(srv, reqs, traffic, 2.0, clock)
-    # 2 s after its last compiling wave (the third), so more waves than
-    # the 2 s window serves, and the same ones, from the first request on
-    assert 20 <= len(fake.log) < len(rehearsed)
-    assert fake.log == rehearsed[:len(fake.log)]
+    clock.t += 7.3
+    w = closed.run(srv, reqs, traffic, 2.0, clock)
+    # the whole set, in the same waves, some of them not full
+    assert waves == len(w.waves) == len(rehearsed) and fake.log == rehearsed
+    assert sorted(q for wave in rehearsed for q in wave) == list(range(300))
+    assert min(len(wave) for wave in rehearsed) < 4
 
 
-def test_rehearsal_stops_at_its_cap():
-    clock = Clock()
-    fake = ByK(clock, wave_s=0.1, slots=4)
-    srv = Server(fake, FakeEngine())
-    always = FirstWavesCompile(fake, 10 ** 9)
-    waves = closed.rehearse(srv, requests(1000), {"outstanding": 8,
-                                                  "rehearse_max_s": 1.0},
-                            2.0, clock, always)
-    assert srv.outstanding() == 0 and 10 <= waves <= 13
+def test_closed_loop_rate_falls_with_every_slowdown():
+    # waves of one k cost 3.5 times those of the other; a window of whole
+    # waves up to a time would hold a count of them that jumps with speed
+    # and read some slowdowns as gains; a fixed set cannot
+    rates = []
+    for scale in np.linspace(1.0, 1.3, 31):
+        clock = Clock()
+        srv = Server(CostByK(clock, wave_s=0.1 * scale, slots=4),
+                     FakeEngine())
+        w = closed.run(srv, mixed_k(200), {"outstanding": 8}, 2.0, clock)
+        assert w.answered.all() and len(w.rows) == 200
+        rates.append(closed.end_to_end(w)["queries_per_s"])
+    assert np.all(np.diff(rates) < 0)
+
+
+class Answers:
+    """A query engine that answers at once."""
+
+    def knn(self, q, k, valid_rows, **_):
+        n = q.shape[0]
+        per_query = np.zeros((n,), np.int32)
+        return KnnResult(dists=np.zeros((n, k), np.float32),
+                         positions=np.zeros((n, k), np.int32),
+                         ids=np.zeros((n, k), np.int32), path=per_query,
+                         eapca_pr=per_query, sax_pr=per_query,
+                         accessed=per_query, visited_leaves=per_query)
+
+
+def test_front_end_serves_the_same_waves_at_any_speed():
+    # the program's own front end packs a closed loop's waves by the order
+    # of the requests alone, so the rehearsal meets the window's shapes
+    def waves(wave_s):
+        clock = Clock()
+
+        class Timed(KnnServeEngine):
+            def step(self):
+                clock.t += wave_s
+                return super().step()
+
+        srv = Server(Timed(Answers(), KnnServeConfig(batch_slots=32)),
+                     FakeEngine())
+        w = closed.run(srv, mixed_k(500), {"outstanding": 64}, 2.0, clock)
+        assert w.answered.all() and len(w.rows) == 500
+        return [tuple(w.rows[w.start == t]) for t in np.unique(w.start)]
+
+    fast, slow = waves(0.1), waves(0.37)
+    assert fast == slow and len(fast) > 500 // 32

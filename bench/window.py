@@ -28,7 +28,6 @@ class Window:
     waves: list                   # bench.serving.Wave, in order
     before: dict                  # engine counters as the window opened
     rows: np.ndarray              # the requests these entries are
-    withdrawn: int = 0            # closed loop: outstanding at the close
 
     @property
     def answered(self) -> np.ndarray:
