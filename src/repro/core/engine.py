@@ -434,6 +434,64 @@ def _ooc_refine_block(rows: jax.Array, base: jax.Array, valid: jax.Array,
         d_top, p_top, d_new, pos, k))(d0, p0, d)
 
 
+# -- the raw stream's per-wave selection programs ---------------------------
+#
+# Each is one jitted program per query shape (and, for the per-piece filter,
+# per padded piece shape), where the eager ops they replace compiled one
+# small program each, per shape and per device, and an eager ``lax.map`` or
+# ``fori_loop`` over a closure lowered again on every call.
+
+@jax.jit
+def _leaf_lbs_of(q, endpoints, synopsis, seg_lens, dead):
+    """(Q, L) squared LB_EAPCA of every query to every leaf synopsis, INF
+    at the ``dead`` (empty) leaves."""
+    from repro.core.lower_bounds import lb_eapca_node
+    from repro.core.search import _query_seg_stats
+
+    qp, qp2 = S.prefix_sums(q)
+
+    def one(args):
+        p_row, p2_row = args
+        qm, qs = _query_seg_stats(p_row, p2_row, endpoints)
+        return lb_eapca_node(qm, qs, synopsis, seg_lens)
+
+    return jnp.where(dead[None, :], INF, jax.lax.map(one, (qp, qp2)))
+
+
+@functools.partial(jax.jit, static_argnames=("max_depth", "l_max"))
+def _seed_leaves(tree, leaf_rank, q, lbs, *, max_depth: int, l_max: int):
+    """Phase 1's leaves: each query's home leaf rank and its ``l_max`` best
+    leaf ranks by LB_EAPCA."""
+    from repro.core.tree import route_to_leaf
+
+    home = leaf_rank[route_to_leaf(tree, q, max_depth)]
+    return home, jax.lax.top_k(-lbs, l_max)[1]
+
+
+@jax.jit
+def _leaf_candidates(lbs, bsf, slack):
+    """Phase 2's leaf-level test: which leaves some query cannot prune
+    against its ``bsf``, and how many leaves each query keeps."""
+    cand = lbs * slack < bsf[:, None]
+    return jnp.any(cand, axis=0), jnp.sum(cand, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("series_len", "mode"))
+def _sax_filter_block(q_paa, codes, valid, lbs, ranks, d, shared, slack,
+                      alive_counts, *, series_len: int, mode: str):
+    """Phase 3's filter of one padded piece: the rows some query cannot
+    prune by LB_SAX (raised to its leaf's LB_EAPCA) against the smaller of
+    its running kth distance and ``shared``, and each query's running count
+    of rows it keeps. Rows beyond ``valid`` are pad and never kept."""
+    lb_row = jnp.maximum(kops.lb_sax(q_paa, codes, series_len, mode=mode),
+                         lbs[:, ranks])
+    bsf = jnp.minimum(d[:, -1], shared)
+    live = ((lb_row * slack < bsf[:, None])
+            & (jnp.arange(codes.shape[0]) < valid)[None, :])
+    return (alive_counts + jnp.sum(live, axis=1, dtype=jnp.int32),
+            jnp.any(live, axis=0))
+
+
 # -- codec-aware streaming (format v3 encoded leaves) -----------------------
 #
 # With a lossy codec the streamed bytes are approximations, so decoded
@@ -925,49 +983,41 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
 
     def _leaf_lbs(self, q: jax.Array) -> jax.Array:
         """(Q, L) squared LB_EAPCA of every query to every leaf synopsis."""
-        from repro.core.lower_bounds import lb_eapca_node
-        from repro.core.search import _query_seg_stats
-
-        qp, qp2 = S.prefix_sums(q)
-
-        def one(args):
-            p_row, p2_row = args
-            qm, qs = _query_seg_stats(p_row, p2_row, self._leaf_endpoints)
-            return lb_eapca_node(qm, qs, self._leaf_synopsis,
-                                 self._leaf_seg_lens)
-
-        lbs = jax.lax.map(one, (qp, qp2))
-        dead = jnp.asarray(self._leaf_count) <= 0
-        return jnp.where(dead[None, :], INF, lbs)
+        return _leaf_lbs_of(q, self._leaf_endpoints, self._leaf_synopsis,
+                            self._leaf_seg_lens,
+                            np.asarray(self._leaf_count) <= 0)
 
     def _seed_candidates(self, q: jax.Array, cfg: SearchConfig):
         """Phase 1's inputs (Alg. 11): the (Q, L) LB_EAPCA of every query
         to every leaf, and on the host each query's home leaf rank and its
         ``l_max`` best leaf ranks by that bound."""
-        from repro.core.tree import route_to_leaf
-
         lbs = self._leaf_lbs(q)                              # (Q, L)
-        home_nodes = route_to_leaf(self.saved.tree, q, self.saved.max_depth)
-        home_ranks = self._syncs.read(self._leaf_rank)[
-            self._syncs.read(home_nodes)]
         l_max = min(cfg.l_max, self.saved.num_leaves)
-        _, best = jax.lax.top_k(-lbs, l_max)                 # (Q, l_max)
-        return lbs, home_ranks, self._syncs.read(best)
+        home_ranks, best = _seed_leaves(
+            self.saved.tree, self._leaf_rank, q, lbs,
+            max_depth=self.saved.max_depth, l_max=l_max)
+        return lbs, self._syncs.read(home_ranks), self._syncs.read(best)
 
     def _needed_leaves(self, lbs: jax.Array, bsf: jax.Array, slack,
                        seeded: list[int]):
         """Phase 2 (Alg. 12): the leaves some query cannot prune against
         its ``bsf``, less the ``seeded`` ones already read, and each query's
         leaf-level pruning ratio."""
-        cand = lbs * slack < bsf[:, None]                    # (Q, L)
-        needed = np.array(self._syncs.read(jnp.any(cand, axis=0)))
+        any_q, per_q = _leaf_candidates(lbs, bsf, slack)
+        needed = np.array(self._syncs.read(any_q))
         needed[seeded] = False
         n_alive = max(int((np.asarray(self._leaf_count) > 0).sum()), 1)
-        eapca_pr = 1.0 - self._syncs.read(
-            jnp.sum(cand, axis=1)).astype(np.float32) / n_alive
+        eapca_pr = 1.0 - self._syncs.read(per_q).astype(np.float32) / n_alive
         return needed, eapca_pr
 
-    def _stream_knn(self, q: jax.Array, cfg: SearchConfig) -> KnnResult:
+    def _stream_knn(self, q: jax.Array, cfg: SearchConfig,
+                    exchange=None) -> KnnResult:
+        """``exchange``, where given, is called once at the end of phase 1
+        with the phase-1 top-k ``(d, p)`` and returns a ``(Q,)`` bound on
+        each query's final kth distance (``dist-ooc``'s best-so-far shared
+        across shards); phases 2-3 then prune against the smaller of that
+        bound and the running kth distance. The bound must be the kth
+        distance of k real rows, so pruning stays no-false-dismissal."""
         from repro.data.pipeline import make_chunk_reader
 
         k = cfg.k
@@ -1014,12 +1064,17 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                          if int(self._leaf_count[r]) > 0]
                 seed_rows = sum(cnt for _, cnt, _ in seeds)
                 d, p = refine_all(d, p, seeds)
+            # the bound shared across shards (none: INF, which leaves each
+            # query's running kth distance as its bound)
+            shared = jnp.asarray(np.full((qn,), np.inf, np.float32)
+                                 if exchange is None else exchange(d, p))
 
             # -- phase 2: leaf-level pruning over resident synopses ----------
             with span("repro.ooc.select") as sel:
                 slack = jnp.float32(1.0 - cfg.lb_slack)
-                needed, eapca_pr = self._needed_leaves(lbs, d[:, k - 1],
-                                                       slack, seeded)
+                needed, eapca_pr = self._needed_leaves(
+                    lbs, d[:, k - 1] if exchange is None
+                    else jnp.minimum(d[:, k - 1], shared), slack, seeded)
                 pieces = self._runs(needed, R)
                 sel.set_metadata(runs=len(pieces))
 
@@ -1057,16 +1112,11 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                         ranks = np.zeros((pad_to,), np.int32)
                         ranks[:cnt] = self._srank[start:start + cnt]
                         self._t["sax_rows_read"] += cnt
-                        lb_row = jnp.maximum(
-                            kops.lb_sax(q_paa, codes, n, mode=kmode),
-                            lbs[:, ranks])                    # (Q, pad_to)
-                        bsf = d[:, k - 1]
-                        live = ((lb_row * slack < bsf[:, None])
-                                & (jnp.arange(pad_to) < cnt)[None, :])
-                        alive_counts = alive_counts + jnp.sum(
-                            live, axis=1, dtype=jnp.int32)
-                        alive = self._syncs.read(
-                            jnp.any(live, axis=0))[:cnt]
+                        alive_counts, live = _sax_filter_block(
+                            q_paa, codes, jnp.int32(cnt), lbs, ranks, d,
+                            shared, slack, alive_counts, series_len=n,
+                            mode=kmode)
+                        alive = self._syncs.read(live)[:cnt]
                     with span("repro.ooc.select") as sel:
                         runs = _alive_runs(alive, start)
                         sel.set_metadata(runs=len(runs))
@@ -1669,7 +1719,12 @@ class DistTelemetry(_TelemetrySection):
     ``BALANCE_WARN_RATIO``). ``row_range`` is each shard's assigned
     ``[lo, hi)`` file-row range and ``rows_touched`` the absolute extremes
     its readers actually touched (``None`` until the first read) — the
-    residency-confinement proof: touched ⊆ assigned, always."""
+    residency-confinement proof: touched ⊆ assigned, always. Where the
+    shards share one bound (the raw per-query stream over more than one
+    shard), ``phase1_rows`` counts the rows each shard read in phase 1 and
+    ``exchange_wait_seconds`` the time each waited at the exchange for the
+    slowest shard; ``merge_seconds`` sums both collective merges (the
+    exchange's and the final one) over all calls."""
     shards: int = 0
     rows_streamed: list = dataclasses.field(default_factory=list)
     read_wait_seconds: list = dataclasses.field(default_factory=list)
@@ -1680,6 +1735,9 @@ class DistTelemetry(_TelemetrySection):
     balance_warning: bool = False
     row_range: list = dataclasses.field(default_factory=list)
     rows_touched: list = dataclasses.field(default_factory=list)
+    phase1_rows: list = dataclasses.field(default_factory=list)
+    exchange_wait_seconds: list = dataclasses.field(default_factory=list)
+    merge_seconds: float = 0.0
 
 
 @dataclasses.dataclass

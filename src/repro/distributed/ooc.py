@@ -16,16 +16,26 @@ row ranges balanced by row count; each shard then
   codec-certified encoded stream and the wave-fused dedup'd run schedule
   both come along for free, because each shard is a full
   :class:`~repro.core.engine.OutOfCoreLocalBackend` over its range view;
+* shares one best-so-far across shards (the paper's parallel query
+  answering prunes every worker against one BSF): on the raw per-query
+  stream each shard runs phase 1 (its home leaf and its ``l_max`` best
+  leaves), the shards' phase-1 top-k go through the collective merge once,
+  and every shard prunes phases 2-3 against the global kth distance as
+  well as its own running one (``_BoundExchange``). The codec and wave
+  streams keep per-shard bounds;
 * merges per-shard top-k triplets **in difference form** through the same
   ``shard_map`` + ``all_gather`` collective idiom as
   ``repro.distributed.search``.
 
-Exactness / bit-identity argument: each shard's answer is the exact top-k
-of its row range with the same difference-form squared-ED arithmetic as
-every other backend, and shards partition the file into *ascending
-contiguous* ranges. ``jax.lax.top_k`` breaks ties toward the lower index,
-so the shard-major concatenation the collective merge sorts resolves equal
-distances toward the lower file position — exactly the tie-break the
+Exactness / bit-identity argument: the shared bound is the kth distance
+of k real rows, so it bounds every query's final kth distance from above,
+and a row whose lower bound reaches it cannot be an answer. Each shard's
+answer is therefore the exact top-k of its row range, with the same
+difference-form squared-ED arithmetic as every other backend, and shards
+partition the file into *ascending contiguous* ranges. ``jax.lax.top_k``
+breaks ties toward the lower index, so the shard-major concatenation the
+collective merge sorts resolves equal distances toward the lower file
+position — exactly the tie-break the
 single-host fold (:func:`repro.core.search._merge_topk` in file order)
 produces. Hence distances, positions, and ids match ``LocalBackend`` /
 ``ooc-local`` bit for bit for every shard count, codec, and
@@ -38,8 +48,11 @@ the device that owns the shard before the collective merge runs.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
@@ -49,6 +62,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.analysis.sanitize import lockdep_task
 from repro.core.engine import OutOfCoreLocalBackend, _OutOfCoreBase
+from repro.core.probes import span
 from repro.core.search import SearchConfig
 from repro.storage.partition import ShardPlan, shard_plan
 
@@ -241,6 +255,54 @@ def _make_collective_merge(mesh):
     return jax.jit(merge)
 
 
+def _shard_results(futures) -> list:
+    """The shards' results in order. Where shards failed, raises the first
+    failure that is not a broken exchange, i.e. the cause and not a shard
+    that was waiting for it."""
+    failed = [e for e in (f.exception() for f in futures) if e is not None]
+    if failed:
+        raise next((e for e in failed
+                    if not isinstance(e, threading.BrokenBarrierError)),
+                   failed[0])
+    return [f.result() for f in futures]
+
+
+class _BoundExchange:
+    """One wave's exchange of phase-1 answers between the shard threads.
+
+    Each shard hands in its phase-1 top-k and waits at a barrier; the last
+    to arrive runs ``merge`` (``{shard: (d, p)}`` -> the ``(Q,)`` global
+    kth distance, on the host, so each shard can use it on its own
+    device), and every shard leaves with that bound. ``waits`` holds the
+    seconds each shard waited for the last to arrive. A shard that fails
+    before the exchange aborts the barrier, so the others raise instead of
+    waiting for it."""
+
+    def __init__(self, shards: int, merge):
+        self._merge = merge
+        self._parts: dict = {}
+        self._last_arrival = 0.0
+        self._barrier = threading.Barrier(shards, action=self._merge_all)
+        self.bound: np.ndarray | None = None
+        self.waits: dict = {}
+
+    def __call__(self, shard: int, d, p) -> np.ndarray:
+        """Hand in ``shard``'s phase-1 ``(d, p)``; returns the bound."""
+        self._parts[shard] = (d, p)
+        arrived = time.perf_counter()
+        self._barrier.wait()
+        self.waits[shard] = self._last_arrival - arrived
+        return self.bound
+
+    def _merge_all(self) -> None:
+        self._last_arrival = time.perf_counter()
+        with span("repro.dist.exchange"):
+            self.bound = self._merge(self._parts)
+
+    def abort(self) -> None:
+        self._barrier.abort()
+
+
 class DistOutOfCoreBackend(_OutOfCoreBase):
     """Sharded out-of-core serving over one saved index (see module docs).
 
@@ -286,6 +348,12 @@ class DistOutOfCoreBackend(_OutOfCoreBase):
                 self._subs.append(OutOfCoreLocalBackend(
                     view, self._config, memory_budget_mb))
         self._merge = _make_collective_merge(mesh)
+        # per-shard exchange accounting (DistTelemetry): rows read in phase
+        # 1, seconds waited at the exchange for the slowest shard, and the
+        # seconds of both merges
+        self._phase1_rows = [0] * self.num_shards
+        self._exchange_wait = [0.0] * self.num_shards
+        self._merge_s = 0.0
         # folded into the engine's plan-cache key: a plan compiled for one
         # mesh must not serve another (different collective program and
         # different shard placement)
@@ -308,13 +376,22 @@ class DistOutOfCoreBackend(_OutOfCoreBase):
     def _fan_plan(self, cfg, wave: bool, q_struct=None):
         subs = [(i, sub) for i, sub in enumerate(self._subs)
                 if self._views[i].num_series > 0]
-        plans = [(i, (sub.make_wave_plan(cfg, q_struct) if wave
-                      else sub._bind(cfg)))
-                 for i, sub in subs]
+        # the raw per-query stream shares one bound across shards; the
+        # codec and wave streams prune each shard against its own
+        shared = (not wave and len(subs) > 1
+                  and self._active_codec(cfg) is None)
+        if shared:
+            plans = [(i, functools.partial(sub._stream_knn, cfg=cfg))
+                     for i, sub in subs]
+        else:
+            plans = [(i, (sub.make_wave_plan(cfg, q_struct) if wave
+                          else sub._bind(cfg)))
+                     for i, sub in subs]
         valid_aware = any(getattr(p, "valid_aware", False) for _, p in plans)
 
         def run(q, valid_rows=None):
-            return self._fan_out(jnp.asarray(q), cfg, plans, valid_rows)
+            return self._fan_out(jnp.asarray(q), cfg, plans, valid_rows,
+                                 shared)
 
         run.valid_aware = valid_aware
         return run
@@ -329,18 +406,73 @@ class DistOutOfCoreBackend(_OutOfCoreBase):
 
     # -- the fan-out / collective-merge call ---------------------------------
 
-    def _run_shard(self, shard: int, plan, q, valid_rows):
+    def _run_shard(self, shard: int, plan, q, valid_rows, exchange=None):
         """One shard's stream, pinned to its mesh device: blocks stage to
-        (and the refine kernels run on) the device that owns the shard."""
-        with jax.default_device(self._devices[shard]):
-            if getattr(plan, "valid_aware", False):
-                res = plan(q, valid_rows=valid_rows)
+        (and the refine kernels run on) the device that owns the shard.
+        With an ``exchange`` the stream stops after phase 1 to hand in its
+        answers, and prunes phases 2-3 against the bound it gets back."""
+        with jax.default_device(self._devices[shard]), \
+                contextlib.ExitStack() as phase:
+            if exchange is None:
+                phase.enter_context(span("repro.dist.shard", shard=shard,
+                                         phase="all"))
+                res = (plan(q, valid_rows=valid_rows)
+                       if getattr(plan, "valid_aware", False) else plan(q))
             else:
-                res = plan(q)
+                sub = self._subs[shard]
+                rows0 = sub._t["rows_streamed"]
+
+                def hand_over(d, p):
+                    phase.close()              # phase 1 ends here
+                    self._phase1_rows[shard] += (sub._t["rows_streamed"]
+                                                 - rows0)
+                    bound = exchange(shard, d, p)
+                    phase.enter_context(span("repro.dist.shard", shard=shard,
+                                             phase="2-3"))
+                    return bound
+
+                phase.enter_context(span("repro.dist.shard", shard=shard,
+                                         phase="1"))
+                try:
+                    res = plan(q, exchange=hand_over)
+                except BaseException:
+                    exchange.abort()
+                    raise
             jax.block_until_ready(res.dists)
         return res
 
-    def _fan_out(self, q, cfg: SearchConfig, plans, valid_rows):
+    def _merge_parts(self, parts: dict, qn: int, k: int):
+        """Merge ``{shard: (d, shard-local p[, ids])}`` top-k parts through
+        the collective merge; absent shards contribute nothing."""
+        t0 = time.perf_counter()
+        empty_d = np.full((qn, k), np.float32(np.inf))
+        empty_i = np.full((qn, k), -1, np.int32)
+        d_parts, p_parts, i_parts = [], [], []
+        for s in range(self.num_shards):
+            part = parts.get(s)
+            if part is None:
+                d_parts.append(empty_d)
+                p_parts.append(empty_i)
+                i_parts.append(empty_i)
+                continue
+            row_lo = self._views[s].row_lo
+            p_local = np.asarray(part[1])
+            p_global = np.where(p_local >= 0, p_local + row_lo,
+                                -1).astype(p_local.dtype)
+            d_parts.append(np.asarray(part[0]))
+            p_parts.append(p_global)
+            i_parts.append(np.asarray(part[2]) if len(part) > 2
+                           else p_global)
+        # each shard's top-k goes straight to its own device for the merge
+        spread = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
+        out = self._merge(*(jax.device_put(np.stack(parts), spread)
+                            for parts in (d_parts, p_parts, i_parts)))
+        jax.block_until_ready(out)
+        self._merge_s += time.perf_counter() - t0
+        return out
+
+    def _fan_out(self, q, cfg: SearchConfig, plans, valid_rows,
+                 shared: bool = False):
         k = cfg.k
         qn = q.shape[0]
         if len(plans) > 1:
@@ -350,39 +482,33 @@ class DistOutOfCoreBackend(_OutOfCoreBase):
             # Under REPRO_SANITIZE=1 lockdep asserts each work item enters
             # and leaves lock-free — pool threads are recycled, so a
             # carried lock would deadlock a later, unrelated item.
+            exchange = None
+            if shared:
+                def kth(parts):
+                    md, _, _ = self._merge_parts(parts, qn, k)
+                    return np.asarray(md)[:, k - 1]
+
+                exchange = _BoundExchange(len(plans), kth)
             run = lockdep_task(
-                lambda ip: self._run_shard(ip[0], ip[1], q, valid_rows),
+                lambda ip: self._run_shard(ip[0], ip[1], q, valid_rows,
+                                           exchange),
                 name="dist-ooc-shard")
             with ThreadPoolExecutor(max_workers=len(plans),
                                     thread_name_prefix="repro-dist-shard"
                                     ) as pool:
-                results = list(pool.map(run, plans))
+                results = _shard_results([pool.submit(run, ip)
+                                          for ip in plans])
+            if exchange is not None:
+                for shard, wait in exchange.waits.items():
+                    self._exchange_wait[shard] += wait
         else:
             results = [self._run_shard(i, p, q, valid_rows)
                        for i, p in plans]
 
-        by_shard = dict(zip((i for i, _ in plans), results))
-        empty_d = np.full((qn, k), np.float32(np.inf))
-        empty_i = np.full((qn, k), -1, np.int32)
-        d_parts, p_parts, i_parts = [], [], []
-        for s in range(self.num_shards):
-            res = by_shard.get(s)
-            if res is None:
-                d_parts.append(empty_d)
-                p_parts.append(empty_i)
-                i_parts.append(empty_i)
-                continue
-            row_lo = self._views[s].row_lo
-            p_local = np.asarray(res.positions)
-            d_parts.append(np.asarray(res.dists))
-            p_parts.append(np.where(p_local >= 0, p_local + row_lo,
-                                    -1).astype(p_local.dtype))
-            i_parts.append(np.asarray(res.ids))
-
-        # each shard's top-k goes straight to its own device for the merge
-        spread = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
-        md, mp, mi = self._merge(*(jax.device_put(np.stack(parts), spread)
-                                   for parts in (d_parts, p_parts, i_parts)))
+        with span("repro.dist.merge"):
+            md, mp, mi = self._merge_parts(
+                {i: (res.dists, res.positions, res.ids)
+                 for (i, _), res in zip(plans, results)}, qn, k)
         self._t["calls"] += 1
 
         # per-query telemetry: exact counters sum; pruning ratios recombine
@@ -445,6 +571,9 @@ class DistOutOfCoreBackend(_OutOfCoreBase):
                               for s in range(self.num_shards)],
                 "rows_touched": [list(t) if (t := v.rows_touched()) else None
                                  for v in self._views],
+                "phase1_rows": list(self._phase1_rows),
+                "exchange_wait_seconds": list(self._exchange_wait),
+                "merge_seconds": self._merge_s,
             },
         }
 

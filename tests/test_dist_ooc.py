@@ -12,6 +12,7 @@ Layout mirrors the environment the backend runs in:
 * a lean subprocess leg covers multi-shard on a plain 1-device machine
   (marked slow, skipped when the in-process matrix already ran).
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -541,3 +542,168 @@ def test_dist_ooc_two_device_subprocess():
                          cwd="/root/repo", capture_output=True, text=True,
                          timeout=600)
     assert "DIST_OOC_MESH2_OK" in res.stdout, res.stderr[-3000:]
+
+
+# ---------------------------------------------------------------------------
+# one best-so-far shared across shards: 4 forced host devices
+# ---------------------------------------------------------------------------
+
+_SHARED_BSF_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys; sys.path.insert(0, "src")
+    import warnings; warnings.simplefilter("ignore", RuntimeWarning)
+    import tempfile
+    import jax
+    import numpy as np
+    from repro import api
+    from repro.core.engine import OutOfCoreLocalBackend
+
+    rng = np.random.default_rng(16)
+    n_series, length = 1 << 13, 64
+    data = np.cumsum(rng.standard_normal((n_series, length)), axis=1
+                     ).astype(np.float32)
+    q = (data[rng.integers(0, n_series, 16)]
+         + 0.5 * rng.standard_normal((16, length))).astype(np.float32)
+    # few seeded leaves, so phases 2-3 have work to prune
+    over = {"l_max": 2}
+
+    def brute(k):
+        d = ((data[None, :, :] - q[:, None, :]) ** 2).sum(-1)
+        order = np.argsort(d, axis=1, kind="stable")[:, :k]
+        return np.take_along_axis(d, order, axis=1)
+
+    # spy: the bound each call of phase 2 prunes against, by backend
+    seen = {}
+    needed = OutOfCoreLocalBackend._needed_leaves
+
+    def spy(self, lbs, bsf, slack, seeded):
+        seen[id(self)] = np.asarray(bsf)
+        return needed(self, lbs, bsf, slack, seeded)
+
+    OutOfCoreLocalBackend._needed_leaves = spy
+
+    def alone(be, sub, dev, cfg):
+        # one shard's stream as the fan-out ran it before the exchange:
+        # returns its phase-1 top-k distances, rows and fetches
+        got = {}
+
+        def keep(d, p):
+            got["d"] = np.asarray(d)
+            return np.full(d.shape[0], np.inf, np.float32)  # no bound
+
+        t0 = dict(sub._t)
+        with jax.default_device(dev):
+            sub._stream_knn(jax.numpy.asarray(q), cfg, exchange=keep)
+        return (got["d"], sub._t["rows_streamed"] - t0["rows_streamed"],
+                sub._t["blocks"] - t0["blocks"])
+
+    cfg_build = api.IndexConfig(build=api.BuildConfig(leaf_capacity=64))
+    with tempfile.TemporaryDirectory() as d:
+        with api.Hercules.create(d + "/i", cfg_build, data=data) as hx:
+            for k in (1, 10):
+                ref = hx.engine("local").knn(q, k=k, **over)
+                want = brute(k)
+                for prefetch in ("sync", "thread"):
+                    eng = hx.engine("dist-ooc", shards=4, memory_budget_mb=1,
+                                    prefetch=prefetch)
+                    be = eng.backend
+                    t0 = [dict(s._t) for s in be._subs]
+                    seen.clear()
+                    res = eng.knn(q, k=k, **over)
+                    # exact: bit-identical to local, and brute force's
+                    # distances
+                    for a, b in (("dists", ref.dists), ("ids", ref.ids),
+                                 ("positions", ref.positions)):
+                        assert np.array_equal(np.asarray(getattr(res, a)),
+                                              np.asarray(b)), (k, a)
+                    np.testing.assert_allclose(np.asarray(res.dists), want,
+                                               rtol=1e-4, atol=1e-3)
+                    rows = sum(s._t["rows_streamed"] - t["rows_streamed"]
+                               for s, t in zip(be._subs, t0))
+                    fetches = sum(s._t["blocks"] - t["blocks"]
+                                  for s, t in zip(be._subs, t0))
+                    shared = [seen[id(s)] for s in be._subs]
+                    t = eng.telemetry().dist
+                    assert len(t.phase1_rows) == 4 and min(t.phase1_rows) > 0
+                    assert len(t.exchange_wait_seconds) == 4
+                    assert min(t.exchange_wait_seconds) >= 0.0
+                    assert t.merge_seconds > 0.0
+
+                    cfg = be.resolve(k, over)
+                    parts = [alone(be, s, dev, cfg)
+                             for s, dev in zip(be._subs, be._devices)]
+                    # the bound is the merged kth distance of every
+                    # shard's phase-1 answers, on every shard
+                    merged = np.sort(np.concatenate([p[0] for p in parts],
+                                                    axis=1), axis=1)[:, k - 1]
+                    for b in shared:
+                        assert np.array_equal(b, merged), (k, b, merged)
+                    # ... and no larger than ooc-local's after phase 1: the
+                    # shards' seed sets hold ooc-local's
+                    local = hx.engine("ooc-local", memory_budget_mb=4,
+                                      prefetch=prefetch).backend
+                    own = alone(local, local, jax.devices()[0],
+                                local.resolve(k, over))[0][:, k - 1]
+                    assert (merged <= own).all(), (merged, own)
+                    assert (merged < own).any() or k == 1
+                    # the shared bound reads no more than the shards alone
+                    rows_alone = sum(p[1] for p in parts)
+                    fetches_alone = sum(p[2] for p in parts)
+                    assert rows <= rows_alone, (k, rows, rows_alone)
+                    assert fetches <= fetches_alone, (k, fetches,
+                                                      fetches_alone)
+                    print("SHARED", k, prefetch, rows, rows_alone, fetches,
+                          fetches_alone)
+
+            # a shard that fails before the exchange fails the call with
+            # its own error; the others do not wait for it
+            eng = hx.engine("dist-ooc", shards=4, memory_budget_mb=1)
+            sub = eng.backend._subs[2]
+
+            def broken(q, cfg):
+                raise RuntimeError("shard 2 broke")
+
+            sub._seed_candidates = broken
+            try:
+                eng.knn(q, k=1, **over)
+            except RuntimeError as e:
+                assert "shard 2 broke" in str(e), e
+            else:
+                raise AssertionError("the broken shard went unnoticed")
+            del sub._seed_candidates
+            res = eng.knn(q, k=1, **over)
+            assert np.array_equal(np.asarray(res.dists),
+                                  np.asarray(hx.engine("local").knn(
+                                      q, k=1, **over).dists))
+
+            # the exchange under frequent thread switches: every wave
+            # exact
+            eng = hx.engine("dist-ooc", shards=4, memory_budget_mb=1,
+                            prefetch="thread")
+            ref = hx.engine("local").knn(q, k=10, **over)
+            switch = sys.getswitchinterval()
+            sys.setswitchinterval(1e-6)
+            try:
+                for _ in range(8):
+                    res = eng.knn(q, k=10, **over)
+                    assert np.array_equal(np.asarray(res.dists),
+                                          np.asarray(ref.dists))
+                    assert np.array_equal(np.asarray(res.ids),
+                                          np.asarray(ref.ids))
+            finally:
+                sys.setswitchinterval(switch)
+            t = eng.telemetry().dist
+            assert len(t.exchange_wait_seconds) == 4
+            assert all(r > 0 for r in t.phase1_rows)
+    print("DIST_SHARED_BSF_OK")
+""")
+
+
+def test_dist_ooc_shared_bound_four_devices():
+    res = subprocess.run([sys.executable, "-c", _SHARED_BSF_SCRIPT],
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))),
+                         capture_output=True, text=True, timeout=600)
+    assert "DIST_SHARED_BSF_OK" in res.stdout, (res.stdout[-3000:],
+                                               res.stderr[-3000:])

@@ -421,6 +421,30 @@ class TestOocSaxStreaming:
             assert st_sax["rows_streamed"] <= st_no["rows_streamed"]
 
 
+    @pytest.mark.parametrize("k", [1, 3])
+    def test_repeated_wave_lowers_nothing(self, compacted_dir, queries, k):
+        """Every program of the streamed search is cached by shape: a wave
+        served again lowers nothing (phase 1's LB_EAPCA map and tree
+        descent, phase 2's leaf test and phase 3's per-piece filter once
+        lowered again on every call or compiled one program per op)."""
+        lowered = []
+
+        def on(event, secs, **_):
+            if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                lowered.append(event)
+
+        with Hercules.open(compacted_dir) as hx:
+            eng = hx.engine("ooc-local", memory_budget_mb=BUDGET_MB)
+            first = eng.knn(queries, k=k)
+            jax.monitoring.register_event_duration_secs_listener(on)
+            try:
+                again = eng.knn(queries, k=k)
+            finally:
+                jax.monitoring.unregister_event_duration_listener(on)
+            _same(first, again)
+            assert eng.backend.stats()["sax_rows_read"] > 0
+            assert lowered == []
+
 class TestRandomChunkings:
     @settings(max_examples=5, deadline=None)
     @given(st.data())
