@@ -81,7 +81,11 @@ old dict access                         Telemetry field
                                         carries ``bytes_streamed`` and the
                                         ``codec_refine_rows`` /
                                         ``codec_fallbacks`` counters, and
-                                        the stream's ``host_syncs``)
+                                        the stream's ``host_syncs``;
+                                        ``blocks``: fetches, ``extents``:
+                                        the leaf extents and alive runs
+                                        they held, so ``extents /
+                                        blocks`` is the packing ratio)
 ``t["dist"]["rows_streamed" | ...]``    ``t.dist.rows_streamed`` ...
                                         (per-shard lists; ``None`` — key
                                         absent — except under ``dist-ooc``)

@@ -416,20 +416,19 @@ def _ooc_merge(d0, p0, d1, p1, *, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def _ooc_refine_block(rows: jax.Array, base: jax.Array, valid: jax.Array,
-                      queries: jax.Array, d0, p0, *, k: int):
+def _ooc_refine_block(rows: jax.Array, pos: jax.Array, queries: jax.Array,
+                      d0, p0, *, k: int):
     """Merge exact difference-form distances of one padded row block into
-    each query's running top-k (rows beyond ``valid`` are masked).
+    each query's running top-k. ``pos`` (rows,) int32 is each row's global
+    layout position, −1 on pad rows (masked to INF), so one block may hold
+    several extents back to back.
 
     The distances are mapped one query at a time (a (rows, n) temporary,
     not (Q, rows, n)); the merge is vmapped: a ``top_k`` inside the map's
     loop body takes the TPU compiler ~25 s per block shape, vmapped ~1 s."""
-    r = rows.shape[0]
-    pos = base + jnp.arange(r, dtype=jnp.int32)
-    live = jnp.arange(r) < valid
     d = jax.lax.map(lambda q: jnp.sum(jnp.square(rows - q[None, :]), axis=1),
                     queries)
-    d = jnp.where(live[None, :], d, INF)
+    d = jnp.where((pos >= 0)[None, :], d, INF)
     return jax.vmap(lambda d_top, p_top, d_new: _merge_topk(
         d_top, p_top, d_new, pos, k))(d0, p0, d)
 
@@ -606,6 +605,32 @@ def _difficulty_from_leaf_lbs(lbs) -> np.ndarray:
     return near.sum(axis=1).astype(np.float32) / n_alive
 
 
+def _block_positions(extents, pad_to: int) -> np.ndarray:
+    """(pad_to,) int32 layout positions of ``extents`` read back to back
+    into one block, −1 on the pad rows after them."""
+    pos = np.full((pad_to,), -1, np.int32)
+    at = 0
+    for start, count in extents:
+        pos[at:at + count] = np.arange(start, start + count, dtype=np.int32)
+        at += count
+    return pos
+
+
+def _pack_extents(extents, cap: int) -> list[list[tuple[int, int]]]:
+    """Greedy blocks of consecutive ``(start, count)`` extents, in the
+    order given: a block closes when the next extent would take it past
+    ``cap`` rows (no extent is longer than ``cap``)."""
+    blocks: list[list[tuple[int, int]]] = []
+    rows = 0
+    for start, count in extents:
+        if not blocks or rows + count > cap:
+            blocks.append([])
+            rows = 0
+        blocks[-1].append((start, count))
+        rows += count
+    return blocks
+
+
 def _alive_runs(alive: np.ndarray, base: int) -> list[tuple[int, int]]:
     """Contiguous True runs of a row-survival mask as absolute
     (start, count) pairs — the sub-extents the SAX filter could not prune."""
@@ -634,7 +659,7 @@ class _OutOfCoreBase(BackendBase):
         self._config = config or saved.config.search
         self._perm = jnp.asarray(saved.small["perm"])
         self._syncs = HostSyncs()
-        self._t = {"calls": 0, "blocks": 0, "rows_streamed": 0,
+        self._t = {"calls": 0, "blocks": 0, "extents": 0, "rows_streamed": 0,
                    "bytes_streamed": 0, "sax_rows_read": 0,
                    "read_seconds": 0.0, "read_wait_seconds": 0.0,
                    "overlap_blocks": 0,
@@ -713,11 +738,13 @@ class _OutOfCoreBase(BackendBase):
         safe = jnp.clip(p, 0, self._perm.shape[0] - 1)
         return jnp.where(p >= 0, self._perm[safe], -1)
 
-    def _count(self, rows: int, row_bytes: int | None = None) -> None:
-        """Account one streamed block: ``row_bytes`` defaults to the raw
-        float32 width; codec streams pass their encoded width so
-        ``bytes_streamed`` reflects the real disk traffic."""
+    def _count(self, rows: int, row_bytes: int | None = None,
+               extents: int = 1) -> None:
+        """Account one streamed block of ``extents`` extents: ``row_bytes``
+        defaults to the raw float32 width; codec streams pass their encoded
+        width so ``bytes_streamed`` reflects the real disk traffic."""
         self._t["blocks"] += 1
+        self._t["extents"] += extents
         self._t["rows_streamed"] += rows
         self._t["bytes_streamed"] += rows * (
             4 * self.saved.series_len if row_bytes is None else row_bytes)
@@ -973,9 +1000,9 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
         return lambda q: self._stream_knn(jnp.asarray(q), cfg)
 
     def _pad_bucket(self, count: int, cap: int) -> int:
-        """Pad a piece to a small set of shapes (powers of two between
+        """Pad a block to a small set of shapes (powers of two between
         max_leaf and the streaming cap) so refine kernels compile O(log)
-        times while tiny pieces don't pay a full-budget zero-fill/copy."""
+        times while small blocks don't pay a full-budget zero-fill/copy."""
         b = max(self.saved.max_leaf, 1)
         while b < count:
             b <<= 1
@@ -1023,31 +1050,38 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
         k = cfg.k
         qn = q.shape[0]
         n = self.saved.series_len
-        max_leaf = self.saved.max_leaf
         R = self.stream_rows()
         rows_before = self._t["rows_streamed"]
         d = jnp.full((qn, k), INF)
         p = jnp.full((qn, k), -1, jnp.int32)
 
         # every raw-row fetch of this call (seeded leaves, then alive runs)
-        # flows through one reader: extents are submitted ahead of
-        # consumption, so with prefetch="thread" the next extent's page
-        # faults land in a slot buffer while the current one refines
+        # flows through one reader, and each fetch is one block of up to R
+        # rows packed from many extents: one read, one stage and one refine
+        # dispatch a block. Blocks are submitted ahead of consumption, so
+        # with prefetch="thread" the next block's page faults land in a
+        # slot buffer while the current one refines
         lrd_reader = make_chunk_reader(self._lrd(), R, n,
                                        prefetch=cfg.prefetch)
         lsd_reader = None
 
-        def refine_all(d, p, extents):
-            """Refine (start, cnt, pad_to) extents — all submitted before
-            the first is consumed, the reader's lookahead window."""
-            for start, cnt, pad_to in extents:
-                lrd_reader.submit(start, cnt, pad_to)
-            for start, cnt, _ in extents:
+        def refine_all(d, p, blocks):
+            """Refine blocks of (start, cnt) extents, each read back to back
+            into one slot — all submitted before the first is consumed, the
+            reader's lookahead window. Rows keep their visit order, and the
+            merge keeps the carry first, so ties resolve as extent by
+            extent."""
+            sized = [(b, sum(c for _, c in b)) for b in blocks]
+            for block, rows_in in sized:
+                lrd_reader.submit_extents(block,
+                                          self._pad_bucket(rows_in, R))
+            for block, rows_in in sized:
                 rows = lrd_reader.stage(lrd_reader.get())
-                with span("repro.ooc.refine", rows=cnt):
-                    d, p = _ooc_refine_block(rows, jnp.int32(start),
-                                             jnp.int32(cnt), q, d, p, k=k)
-                self._count(cnt)
+                pos = _block_positions(block, rows.shape[0])
+                with span("repro.ooc.refine", rows=rows_in,
+                          extents=len(block)):
+                    d, p = _ooc_refine_block(rows, pos, q, d, p, k=k)
+                self._count(rows_in, extents=len(block))
             return d, p
 
         try:
@@ -1059,11 +1093,10 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                 lbs, home_ranks, best = self._seed_candidates(q, cfg)
                 seeded = sorted(set(int(r) for r in home_ranks if r >= 0)
                                 | set(int(r) for r in best.ravel()))
-                seeds = [(int(self._leaf_start[r]), int(self._leaf_count[r]),
-                          max_leaf) for r in seeded
-                         if int(self._leaf_count[r]) > 0]
-                seed_rows = sum(cnt for _, cnt, _ in seeds)
-                d, p = refine_all(d, p, seeds)
+                seeds = [(int(self._leaf_start[r]), int(self._leaf_count[r]))
+                         for r in seeded if int(self._leaf_count[r]) > 0]
+                seed_rows = sum(cnt for _, cnt in seeds)
+                d, p = refine_all(d, p, _pack_extents(seeds, R))
             # the bound shared across shards (none: INF, which leaves each
             # query's running kth distance as its bound)
             shared = jnp.asarray(np.full((qn,), np.inf, np.float32)
@@ -1088,8 +1121,7 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
             # phase-3 filter never saw are not rows it pruned)
             alive_counts = jnp.full((qn,), seed_rows, jnp.int32)
             if not use_sax:
-                d, p = refine_all(d, p, [(s, c, self._pad_bucket(c, R))
-                                         for s, c in pieces])
+                d, p = refine_all(d, p, _pack_extents(pieces, R))
             else:
                 m_sax = int(self._lsd().shape[1])
                 q_paa = S.paa(q, m_sax)
@@ -1120,9 +1152,10 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                     with span("repro.ooc.select") as sel:
                         runs = _alive_runs(alive, start)
                         sel.set_metadata(runs=len(runs))
-                    d, p = refine_all(d, p,
-                                      [(s0, c0, self._pad_bucket(c0, R))
-                                       for s0, c0 in runs])
+                    # a piece's runs fit one block (a piece is at most R
+                    # rows), and blocks never cross pieces: the next
+                    # piece's filter reads the top-k after this refine
+                    d, p = refine_all(d, p, _pack_extents(runs, R))
             self._t["calls"] += 1
         finally:
             self._reap_reader(lrd_reader)
@@ -1363,9 +1396,10 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
                 for start, cnt, _ in extents:
                     rows = lrd_reader.stage(lrd_reader.get())
                     with span("repro.ooc.refine", rows=cnt):
-                        d, p = _ooc_refine_block(rows, jnp.int32(start),
-                                                 jnp.int32(cnt), q, d, p,
-                                                 k=k)
+                        d, p = _ooc_refine_block(
+                            rows, _block_positions([(start, cnt)],
+                                                   rows.shape[0]),
+                            q, d, p, k=k)
                     self._count(cnt)
 
             # -- phase 2: leaf-level pruning, per member -----------------
@@ -1446,8 +1480,9 @@ class OutOfCoreLocalBackend(_OutOfCoreBase):
             for (s0, c0, _), rows in iter_scheduled_chunks(
                     lrd_reader, reqs, still_needed=still_needed):
                 with span("repro.ooc.refine", rows=c0):
-                    d, p = _ooc_refine_block(rows, jnp.int32(s0),
-                                             jnp.int32(c0), q, d, p, k=k)
+                    d, p = _ooc_refine_block(
+                        rows, _block_positions([(s0, c0)], rows.shape[0]),
+                        q, d, p, k=k)
                 self._count(c0)
                 bsf_host["kth"] = self._syncs.read(d[:, k - 1])
             self._t["calls"] += 1
@@ -1663,13 +1698,17 @@ class PruningTelemetry(_TelemetrySection):
 @dataclasses.dataclass
 class OocTelemetry(_TelemetrySection):
     """Streaming counters of the out-of-core backends (absent — ``None``
-    section — for fully-resident backends). ``bytes_streamed`` counts the
-    bytes actually fetched (encoded width under a codec, plus the float32
-    re-check rows), the honest bandwidth number the codec benchmarks key
-    on; ``codec_refine_rows``/``codec_fallbacks`` account the exactness
+    section — for fully-resident backends). ``blocks`` counts fetches and
+    ``extents`` the leaf extents and alive runs they held (the raw stream
+    packs many into one block, so ``extents / blocks`` is its packing
+    ratio). ``bytes_streamed`` counts the bytes actually fetched (encoded
+    width under a codec, plus the float32 re-check rows), the honest
+    bandwidth number the codec benchmarks key on;
+    ``codec_refine_rows``/``codec_fallbacks`` account the exactness
     machinery of format-v3 encoded streams."""
     calls: int = 0
     blocks: int = 0
+    extents: int = 0
     rows_streamed: int = 0
     bytes_streamed: int = 0
     sax_rows_read: int = 0

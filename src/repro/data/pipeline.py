@@ -180,6 +180,37 @@ def _tally(telemetry: dict | None, stats: dict) -> None:
         telemetry[key] = telemetry.get(key, 0) + stats[key]
 
 
+def _checked_extents(extents, pad_to: int | None, capacity: int):
+    """``extents`` as a tuple of int ``(start, count)`` pairs and the
+    resolved ``pad_to``, or ValueError: every count positive and
+    ``sum(counts) <= pad_to <= capacity`` (``pad_to`` defaults to the
+    total). The one bound both readers enforce, so a consumer cannot work
+    under the default sync mode yet break under ``"thread"``."""
+    extents = tuple((int(s), int(c)) for s, c in extents)
+    if not extents:
+        raise ValueError("no extents to read")
+    for _, count in extents:
+        if count <= 0:
+            raise ValueError(f"count must be positive, got {count}")
+    total = sum(c for _, c in extents)
+    pad_to = total if pad_to is None else int(pad_to)
+    if not total <= pad_to <= capacity:
+        raise ValueError(f"pad_to={pad_to} outside [count={total}, "
+                         f"slot capacity={capacity}]")
+    return extents, pad_to
+
+
+def _fill_extents(buf: np.ndarray, rows, extents, pad_to: int) -> None:
+    """Copy ``extents`` of ``rows`` into ``buf`` back to back, in the order
+    given, and zero the rest of ``buf[:pad_to]``."""
+    at = 0
+    for start, count in extents:
+        buf[at:at + count] = rows[start:start + count]
+        at += count
+    if pad_to > at:
+        buf[at:pad_to] = 0
+
+
 class SyncChunkReader:
     """Inline reads behind the reader surface (``prefetch="sync"``).
 
@@ -205,30 +236,27 @@ class SyncChunkReader:
         self._closed = False
 
     def submit(self, start: int, count: int, pad_to: int | None = None):
+        self.submit_extents([(start, count)], pad_to)
+
+    def submit_extents(self, extents, pad_to: int | None = None):
+        """Enqueue one block read from several ``(start, count)`` extents,
+        copied back to back in the order given and zero-padded to
+        ``pad_to`` rows."""
         if self._closed:
             raise RuntimeError("reader is closed")
-        if count <= 0:
-            raise ValueError(f"count must be positive, got {count}")
-        pad_to = count if pad_to is None else pad_to
-        # same bound the threaded reader's slots enforce, so a consumer
-        # cannot work under the default sync mode yet break under "thread"
-        if not count <= pad_to <= self._capacity:
-            raise ValueError(f"pad_to={pad_to} outside [count={count}, "
-                             f"slot capacity={self._capacity}]")
-        self._reqs.append((int(start), int(count), int(pad_to)))
+        extents, pad_to = _checked_extents(extents, pad_to, self._capacity)
+        self._reqs.append((extents, pad_to))
 
     def get(self) -> np.ndarray:
         if self._closed:
             raise RuntimeError("reader is closed")
         if not self._reqs:
             raise RuntimeError("get() without a pending submit()")
-        start, count, pad_to = self._reqs.popleft()
-        with _span("repro.ooc.read", rows=count):
+        extents, pad_to = self._reqs.popleft()
+        with _span("repro.ooc.read", rows=sum(c for _, c in extents)):
             t0 = time.perf_counter()
             out = np.empty((pad_to, self._width), self._dtype)
-            out[:count] = self._rows[start:start + count]
-            if pad_to > count:
-                out[count:] = 0
+            _fill_extents(out, self._rows, extents, pad_to)
             dt = time.perf_counter() - t0
         self.stats["read_seconds"] += dt
         self.stats["read_wait_seconds"] += dt
@@ -283,13 +311,14 @@ class AsyncChunkReader:
 
     ``rows`` is any row-sliceable store (an ``np.memmap``, an ndarray, the
     store's concat views). ``submit(start, count, pad_to)`` enqueues one
-    extent; ``get()`` serves extents **strictly in submission order** as
+    extent, ``submit_extents(extents, pad_to)`` one slot fill from several,
+    back to back; ``get()`` serves fills **strictly in submission order** as
     views into one of ``slots`` reusable ``(capacity_rows, width)`` arrays.
     Each view is valid only until the next ``get()`` or ``close()`` — move
-    it off-slot (``stage``) before requesting the next extent. Rows beyond
-    ``count`` up to ``pad_to`` are zero-filled, matching the legacy
+    it off-slot (``stage``) before requesting the next fill. Rows beyond
+    the extents up to ``pad_to`` are zero-filled, matching the legacy
     zero-padded fetch byte for byte. A reader-side exception re-raises at
-    the ``get()`` for the failing extent and ends the stream. ``close()``
+    the ``get()`` for the failing fill and ends the stream. ``close()``
     is idempotent, unblocks the thread wherever it waits, and joins it.
     """
 
@@ -332,11 +361,8 @@ class AsyncChunkReader:
 
     # -- reader thread -------------------------------------------------------
 
-    def _fill(self, buf: np.ndarray, start: int, count: int,
-              pad_to: int) -> None:
-        buf[:count] = self._rows[start:start + count]
-        if pad_to > count:
-            buf[count:pad_to] = 0
+    def _fill(self, buf: np.ndarray, extents, pad_to: int) -> None:
+        _fill_extents(buf, self._rows, extents, pad_to)
 
     def _run(self) -> None:
         while True:
@@ -346,10 +372,10 @@ class AsyncChunkReader:
             sid = self._free.get()
             if sid is None or self._stop.is_set():
                 break
-            start, count, pad_to = req
+            extents, pad_to = req
             t0 = time.perf_counter()
             try:
-                self._fill(self._slots[sid], start, count, pad_to)
+                self._fill(self._slots[sid], extents, pad_to)
             except BaseException as e:          # propagate to the consumer
                 self._ready.put((None, 0, 0.0, e))
                 break
@@ -366,16 +392,18 @@ class AsyncChunkReader:
             raise RuntimeError("reader stream already failed") from self._exc
 
     def submit(self, start: int, count: int, pad_to: int | None = None):
+        self.submit_extents([(start, count)], pad_to)
+
+    def submit_extents(self, extents, pad_to: int | None = None):
+        """Enqueue one slot fill from several ``(start, count)`` extents,
+        copied back to back in the order given and zero-padded to
+        ``pad_to`` rows; served in submission order like ``submit``."""
         self._consumer.check("submit")
         self._check_alive()
-        if count <= 0:
-            raise ValueError(f"count must be positive, got {count}")
-        pad_to = count if pad_to is None else pad_to
-        if not count <= pad_to <= self._slots[0].shape[0]:
-            raise ValueError(f"pad_to={pad_to} outside [count={count}, "
-                             f"slot capacity={self._slots[0].shape[0]}]")
+        extents, pad_to = _checked_extents(extents, pad_to,
+                                           self._slots[0].shape[0])
         self._pending += 1
-        self._requests.put((int(start), int(count), int(pad_to)))
+        self._requests.put((extents, pad_to))
 
     def get(self) -> np.ndarray:
         self._consumer.check("get")

@@ -557,6 +557,7 @@ _SHARED_BSF_SCRIPT = textwrap.dedent("""
     import jax
     import numpy as np
     from repro import api
+    from repro.core import engine as engine_mod
     from repro.core.engine import OutOfCoreLocalBackend
 
     rng = np.random.default_rng(16)
@@ -577,11 +578,34 @@ _SHARED_BSF_SCRIPT = textwrap.dedent("""
     seen = {}
     needed = OutOfCoreLocalBackend._needed_leaves
 
+    # ... and the fetches it made before phase 2, by backend
+    fetched = {}
+
     def spy(self, lbs, bsf, slack, seeded):
         seen[id(self)] = np.asarray(bsf)
+        fetched[id(self)] = self._t["blocks"]
         return needed(self, lbs, bsf, slack, seeded)
 
     OutOfCoreLocalBackend._needed_leaves = spy
+
+    # spy: the leaves each call of phase 1 seeds, by backend
+    seeded = {}
+    seed_candidates = OutOfCoreLocalBackend._seed_candidates
+
+    def seed_spy(self, q, cfg):
+        out = seed_candidates(self, q, cfg)
+        _, home, best = out
+        leaves = ({int(r) for r in home if r >= 0}
+                  | {int(r) for r in best.ravel()})
+        counts = np.asarray(self._leaf_count)
+        seeded[id(self)] = sum(int(counts[r] > 0) for r in leaves)
+        return out
+
+    OutOfCoreLocalBackend._seed_candidates = seed_spy
+    pack = engine_mod._pack_extents
+
+    def per_shard(be, t0, key):
+        return [s._t[key] - t[key] for s, t in zip(be._subs, t0)]
 
     def alone(be, sub, dev, cfg):
         # one shard's stream as the fan-out ran it before the exchange:
@@ -624,6 +648,35 @@ _SHARED_BSF_SCRIPT = textwrap.dedent("""
                     fetches = sum(s._t["blocks"] - t["blocks"]
                                   for s, t in zip(be._subs, t0))
                     shared = [seen[id(s)] for s in be._subs]
+                    # each shard's phase 1 packs its seeded leaves into
+                    # fewer fetches than it has leaves, and the whole call
+                    # into fewer than its extents; one fetch an extent (the
+                    # schedule before packing) reads the same rows a shard
+                    # and answers the same
+                    blocks = per_shard(be, t0, "blocks")
+                    extents = per_shard(be, t0, "extents")
+                    phase1 = [fetched[id(s)] - t["blocks"]
+                              for s, t in zip(be._subs, t0)]
+                    shard_rows = per_shard(be, t0, "rows_streamed")
+                    t1 = [dict(s._t) for s in be._subs]
+                    engine_mod._pack_extents = lambda ext, cap: [
+                        [e] for e in ext]
+                    try:
+                        one = eng.knn(q, k=k, **over)
+                    finally:
+                        engine_mod._pack_extents = pack
+                    for a in ("dists", "ids", "positions"):
+                        assert np.array_equal(np.asarray(getattr(res, a)),
+                                              np.asarray(getattr(one, a)))
+                    assert per_shard(be, t1, "rows_streamed") == shard_rows
+                    assert per_shard(be, t1, "blocks") == extents
+                    assert per_shard(be, t1, "extents") == extents
+                    for s, b1, b, e in zip(be._subs, phase1, blocks,
+                                           extents):
+                        assert b1 < seeded[id(s)], (k, b1, seeded[id(s)])
+                        assert b < e, (k, b, e)
+                    print("PACKED", k, prefetch, phase1, blocks, extents,
+                          [seeded[id(s)] for s in be._subs])
                     t = eng.telemetry().dist
                     assert len(t.phase1_rows) == 4 and min(t.phase1_rows) > 0
                     assert len(t.exchange_wait_seconds) == 4

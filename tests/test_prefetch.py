@@ -184,6 +184,87 @@ class TestChunkReader:
         r.close()
 
 
+class TestMultiExtentSubmit:
+    """One slot filled from several extents: the raw stream's packed
+    block."""
+    ROWS = np.arange(100 * 8, dtype=np.float32).reshape(100, 8) + 1.0
+    EXTENTS = [(40, 3), (5, 4), (90, 10), (12, 1)]
+
+    def _want(self, extents, pad_to):
+        want = np.zeros((pad_to, 8), np.float32)
+        at = 0
+        for start, count in extents:
+            want[at:at + count] = self.ROWS[start:start + count]
+            at += count
+        return want
+
+    @pytest.mark.parametrize("mode", PREFETCH_MODES)
+    def test_back_to_back_in_order_with_zero_pad(self, mode):
+        with make_chunk_reader(self.ROWS, 32, 8, prefetch=mode) as r:
+            r.submit_extents(self.EXTENTS, 32)
+            r.submit_extents([(7, 18)])       # pad_to defaults to the total
+            got = np.array(r.get())
+            assert got.shape == (32, 8)
+            assert np.array_equal(got[:3], self.ROWS[40:43])
+            assert np.array_equal(got[3:7], self.ROWS[5:9])
+            assert np.array_equal(got[7:17], self.ROWS[90:100])
+            assert np.array_equal(got[17], self.ROWS[12])
+            assert not got[18:].any()       # zero-filled pad
+            assert np.array_equal(np.array(r.get()), self.ROWS[7:25])
+            assert r.stats["blocks"] == 2
+        assert _no_reader_threads()
+
+    @pytest.mark.parametrize("mode", PREFETCH_MODES)
+    def test_one_extent_is_submit(self, mode):
+        with make_chunk_reader(self.ROWS, 32, 8, prefetch=mode) as r:
+            r.submit(10, 5, 16)
+            r.submit_extents([(10, 5)], 16)
+            a = np.array(r.get())
+            assert np.array_equal(a, np.array(r.get()))
+            assert np.array_equal(a, self._want([(10, 5)], 16))
+
+    @pytest.mark.parametrize("mode", PREFETCH_MODES)
+    def test_multi_extent_validation(self, mode):
+        with make_chunk_reader(self.ROWS, 16, 8, prefetch=mode) as r:
+            with pytest.raises(ValueError, match="pad_to"):
+                r.submit_extents([(0, 6), (10, 6)], 8)   # rows above pad_to
+            with pytest.raises(ValueError, match="pad_to"):
+                r.submit_extents([(0, 6), (10, 6)], 32)  # above capacity
+            with pytest.raises(ValueError, match="pad_to"):
+                r.submit_extents([(0, 10), (20, 10)])    # total > capacity
+            with pytest.raises(ValueError, match="positive"):
+                r.submit_extents([(0, 4), (9, 0)], 8)
+            with pytest.raises(ValueError, match="no extents"):
+                r.submit_extents([], 8)
+
+    def test_sync_and_thread_fill_identical_slots(self):
+        blocks = [(self.EXTENTS, 32), ([(0, 32)], 32), ([(60, 2)], 8),
+                  ([(3, 1), (70, 20)], 24), (self.EXTENTS[::-1], 18)]
+        got = {}
+        for mode in PREFETCH_MODES:
+            with make_chunk_reader(self.ROWS, 32, 8, prefetch=mode) as r:
+                for extents, pad_to in blocks:
+                    r.submit_extents(extents, pad_to)
+                got[mode] = [np.array(r.get()) for _ in blocks]
+        for a, b, (extents, pad_to) in zip(got["sync"], got["thread"],
+                                           blocks):
+            assert np.array_equal(a, b)
+            assert np.array_equal(a, self._want(extents, pad_to))
+
+    def test_staged_block_is_independent_of_refilled_slot(self):
+        """A staged packed block must not alias its slot: the reader
+        refills both slots with the next blocks while it is in use."""
+        r = make_chunk_reader(self.ROWS, 32, 8, prefetch="thread")
+        r.submit_extents(self.EXTENTS, 32)
+        r.submit_extents([(0, 32)])
+        r.submit_extents([(50, 16), (20, 16)])
+        first = r.stage(r.get())
+        r.stage(r.get())
+        r.get()                             # refills the first block's slot
+        assert np.array_equal(np.asarray(first), self._want(self.EXTENTS, 32))
+        r.close()
+
+
 class TestScheduledChunks:
     """The wave path's demand-scheduled fetch loop (iter_scheduled_chunks)."""
     ROWS = np.arange(100 * 8, dtype=np.float32).reshape(100, 8)
@@ -313,9 +394,9 @@ class TestPrefetchParity:
         rng = np.random.default_rng(1234)
         orig = AsyncChunkReader._fill
 
-        def jittered(self, buf, start, count, pad_to):
+        def jittered(self, buf, extents, pad_to):
             time.sleep(float(rng.uniform(0.0, 0.002)))
-            orig(self, buf, start, count, pad_to)
+            orig(self, buf, extents, pad_to)
 
         monkeypatch.setattr(AsyncChunkReader, "_fill", jittered)
         mem_scan = ScanBackend(data, CFG.search).knn(queries)

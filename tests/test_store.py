@@ -445,6 +445,88 @@ class TestOocSaxStreaming:
             assert eng.backend.stats()["sax_rows_read"] > 0
             assert lowered == []
 
+class TestPackedStream:
+    """ooc-local's raw stream reads many leaf extents or alive runs into one
+    budget-sized block: one read, one stage and one refine a block."""
+
+    @pytest.fixture(scope="class")
+    def hard_queries(self, data_ab):
+        # 10% noise: k = 1 and k = 10 both leave pieces with several
+        # alive runs
+        return np.asarray(make_query_workload(
+            jax.random.PRNGKey(1), data_ab, 8, "10%"))
+
+    @pytest.mark.parametrize("prefetch", ["sync", "thread"])
+    @pytest.mark.parametrize("k", [1, 10])
+    def test_packed_blocks_answer_as_extents(self, compacted_dir,
+                                             scratch_index, data_ab,
+                                             hard_queries, monkeypatch, k,
+                                             prefetch):
+        import repro.core.engine as eng_mod
+
+        seeds, pieces = [], []
+        alive_runs = eng_mod._alive_runs
+        seed_candidates = eng_mod.OutOfCoreLocalBackend._seed_candidates
+
+        def spy_runs(alive, base):
+            pieces.append(alive_runs(alive, base))
+            return pieces[-1]
+
+        def spy_seed(self, q, cfg):
+            seeds.append(seed_candidates(self, q, cfg))
+            return seeds[-1]
+
+        monkeypatch.setattr(eng_mod, "_alive_runs", spy_runs)
+        monkeypatch.setattr(eng_mod.OutOfCoreLocalBackend,
+                            "_seed_candidates", spy_seed)
+        with Hercules.open(compacted_dir) as hx:
+            # stream_rows() = 512: eight full leaves a block
+            eng = hx.engine("ooc-local", memory_budget_mb=BUDGET_MB,
+                            prefetch=prefetch)
+            backend = eng.backend
+            R = backend.stream_rows()
+            counts = np.asarray(backend._leaf_count)
+            st0 = backend.stats()
+            res = eng.knn(hard_queries, k=k)
+            st1 = backend.stats()
+            # the parent schedule, one fetch an extent: same answers and
+            # rows, as many fetches as the packed blocks held extents
+            with monkeypatch.context() as m:
+                m.setattr(eng_mod, "_pack_extents",
+                          lambda extents, cap: [[e] for e in extents])
+                one = eng.knn(hard_queries, k=k)
+            st2 = backend.stats()
+
+        packed = {key: st1[key] - st0[key]
+                  for key in ("blocks", "extents", "rows_streamed")}
+        single = {key: st2[key] - st1[key]
+                  for key in ("blocks", "extents", "rows_streamed")}
+        _same(res, LocalBackend(scratch_index).knn(hard_queries, k=k))
+        _same(res, ScanBackend(data_ab, CFG.search).knn(hard_queries, k=k),
+              positions=False)
+        for f in ("dists", "positions", "ids", "path", "eapca_pr", "sax_pr",
+                  "visited_leaves", "accessed"):
+            assert np.array_equal(np.asarray(getattr(res, f)),
+                                  np.asarray(getattr(one, f))), f
+
+        _, home, best = seeds[0]
+        seeded = (set(int(r) for r in home if r >= 0)
+                  | set(int(r) for r in best.ravel()))
+        seed_rows = sum(int(counts[r]) for r in seeded)
+        n_seeds = sum(int(counts[r] > 0) for r in seeded)
+        alive = pieces[:len(pieces) // 2]          # the packed call's
+        alive_rows = sum(c for runs in alive for _, c in runs)
+        assert seed_rows > R                       # several phase-1 blocks
+        assert max(len(runs) for runs in alive) > 1
+        assert packed["rows_streamed"] == seed_rows + alive_rows
+        assert packed["rows_streamed"] == single["rows_streamed"]
+        assert packed["extents"] == n_seeds + sum(len(r) for r in alive)
+        assert packed["extents"] == single["blocks"] == single["extents"]
+        assert packed["blocks"] <= (-(-seed_rows // R)
+                                    + sum(1 for runs in alive if runs))
+        assert packed["extents"] / packed["blocks"] > 1
+
+
 class TestRandomChunkings:
     @settings(max_examples=5, deadline=None)
     @given(st.data())
